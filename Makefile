@@ -1,11 +1,12 @@
 # Single CI entry: tier-1 tests + the batched-data-plane and serverless
-# bench smokes. Everything runs on any host (simulated fabric + Pallas
-# interpret mode); no TPU required.
+# bench smokes. Everything runs on any host (simulated fabric; Pallas
+# kernels in interpret mode on a CPU); no TPU required. On a TPU host,
+# `python3 chip_smoke.py` drives the compiled device paths.
 
 PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))
 export PYTHONPATH
 
-.PHONY: verify test smoke bench apicheck deps-dev
+.PHONY: verify test smoke bench apicheck
 
 verify: test smoke apicheck
 
@@ -25,11 +26,3 @@ bench:
 	python -m benchmarks.serverless
 	python -m benchmarks.run
 
-# Optional dev deps (see requirements-dev.txt). The CI image bakes only
-# the jax_pallas toolchain; the suite falls back to
-# tests/_hypothesis_fallback.py when hypothesis is absent, but the real
-# package (shrinking, replay) is strictly better when installable.
-deps-dev:
-	python -m pip install -r requirements-dev.txt \
-	  || echo "deps-dev: install failed (offline image?) — tests will" \
-	          "use tests/_hypothesis_fallback.py"

@@ -3,8 +3,9 @@ table from the dry-run artifacts. Prints ``name,us_per_call,derived`` CSV.
 
     PYTHONPATH=src python -m benchmarks.run            # everything
     PYTHONPATH=src python -m benchmarks.run --only fig8
-    PYTHONPATH=src python -m benchmarks.run --smoke    # CI: tiny shapes,
-                                                       # interpret mode
+    PYTHONPATH=src python -m benchmarks.run --smoke    # CI: tiny shapes
+                                                       # (interpret mode
+                                                       # on a CPU)
 """
 
 from __future__ import annotations
@@ -19,8 +20,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 def _smoke() -> None:
     """Tiny-shape regression gate for the batched data plane AND the
-    serverless subsystem: runs in seconds on any host (interpret mode)
-    and fails loudly if a gated path regresses. No files are written."""
+    serverless subsystem: runs in seconds on any host (interpret mode on
+    a CPU) and fails loudly if a gated path regresses. No files are
+    written."""
     from benchmarks.batched_lookup import run_suite
 
     results = run_suite(smoke=True)
@@ -123,6 +125,8 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny batched-path smoke (CI without TPU)")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.smoke:
         _smoke()

@@ -1,6 +1,11 @@
 """Elastic data-parallel training with a KRCORE-style control plane.
 
-    python examples/elastic_train.py     (forces 8 host devices)
+    python examples/elastic_train.py
+
+It uses every device JAX sees: the ladder is the powers of two up to
+``len(jax.devices())``. On a CPU host, run it with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` for eight virtual
+devices.
 
 The trainer pre-compiles a ladder of mesh sizes at boot (the statically-
 initialized DCQPs of the paper); scale events then hit the executable pool
@@ -9,7 +14,6 @@ compile (the Verbs-analogue path). Loss keeps decreasing across resizes.
 """
 
 import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 import sys
 sys.path.insert(0, "src")
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -44,13 +48,15 @@ def init_state():
 
 batch0 = {"tokens": np.zeros((8, 64), np.int32),
           "labels": np.zeros((8, 64), np.int32)}
-tr = ElasticTrainer(cfg, make_step, init_state, ladder=(2, 4, 8),
+n_dev = min(len(jax.devices()), 8)       # the batch of 8 splits evenly
+ladder = tuple(2 ** i for i in range(n_dev.bit_length()))
+tr = ElasticTrainer(cfg, make_step, init_state, ladder=ladder,
                     example_batch=batch0)
-print("prewarming executable ladder (2, 4, 8 workers)...")
+print(f"prewarming executable ladder {ladder} workers...")
 tr.prewarm()
 
 data = SyntheticLM(cfg.vocab, 64, 8, seed=1)
-plan = [(2, 5), (4, 5), (8, 5), (4, 5)]
+plan = [(n, 5) for n in ladder] + [(ladder[-2 if len(ladder) > 1 else 0], 5)]
 for n, steps in plan:
     ev = tr.scale_to(n)
     print(f"scale -> {n} workers: {ev['kind']:>11s} path, "

@@ -22,17 +22,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """Version compat: jax.shard_map (w/ check_vma) landed after 0.4.x;
-    older jax spells it jax.experimental.shard_map.shard_map (check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 def pipeline_apply(stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
                    stage_params: Any, x: jnp.ndarray, mesh: Mesh,
                    axis: str = "stage"):
@@ -85,10 +74,11 @@ def pipeline_apply(stage_fn: Callable[[Any, jnp.ndarray], jnp.ndarray],
         # (all other stages contribute zeros)
         return jax.lax.psum(out_buf, axis)
 
-    fn = _shard_map(
-        per_stage, mesh,
+    fn = jax.shard_map(
+        per_stage, mesh=mesh,
         in_specs=(P(axis), P()),           # params split by stage
-        out_specs=P())                     # outputs replicated
+        out_specs=P(),                     # outputs replicated
+        check_vma=False)
     return fn(stage_params, x)
 
 

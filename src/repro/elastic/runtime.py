@@ -32,7 +32,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from repro.launch.mesh import set_mesh
 
 
 # =========================================================== executable pool
@@ -208,7 +207,7 @@ class ElasticTrainer:
     def _builder(self, n: int):
         def build():
             mesh = self._mesh_for(n)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 step = self.make_step(mesh)
                 if self._example_batch is None:
                     return (mesh, jax.jit(step))
@@ -222,16 +221,7 @@ class ElasticTrainer:
                                                   state_struct)
                 batch_sh = {k: P("data", *([None] * (v.ndim - 1)))
                             for k, v in self._example_batch.items()}
-                if not hasattr(jax, "set_mesh"):
-                    # older jax: jit shardings must be concrete Shardings,
-                    # not bare PartitionSpecs
-                    wrap = lambda t: jax.tree_util.tree_map(  # noqa: E731
-                        lambda p: NamedSharding(mesh, p), t,
-                        is_leaf=lambda x: isinstance(x, P))
-                    state_sh, batch_sh = wrap(state_sh), wrap(batch_sh)
-                    out_sh = (NamedSharding(mesh, P()), state_sh)
-                else:
-                    out_sh = (P(), state_sh)
+                out_sh = (P(), state_sh)
                 fn = jax.jit(step, in_shardings=(state_sh, batch_sh),
                              out_shardings=out_sh)
                 compiled = fn.lower(state_struct, batch_struct).compile()
@@ -264,7 +254,7 @@ class ElasticTrainer:
                 self.state, jax.tree_util.tree_map(
                     lambda s: NamedSharding(mesh, s), spec))
         else:
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 self.state = self._init_state()
         self._mesh, self._step_fn = mesh, fn
         old_n, self.n_workers = self.n_workers, n
@@ -274,10 +264,13 @@ class ElasticTrainer:
         return ev
 
     # -- data plane ---------------------------------------------------------
-    def train_step(self, batch) -> Any:
-        dp = NamedSharding(self._mesh, P("data"))
-        batch = {k: jax.device_put(v, NamedSharding(
+    def place_batch(self, batch) -> Dict[str, Any]:
+        """Shard each batch array's leading axis over the current mesh's
+        data axis (one slice per worker)."""
+        return {k: jax.device_put(v, NamedSharding(
             self._mesh, P("data", *([None] * (v.ndim - 1)))))
             for k, v in batch.items()}
-        loss, self.state = self._step_fn(self.state, batch)
+
+    def train_step(self, batch) -> Any:
+        loss, self.state = self._step_fn(self.state, self.place_batch(batch))
         return loss

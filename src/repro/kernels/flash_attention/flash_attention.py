@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -94,8 +96,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 
 def flash_attention_pallas(q, k, v, *, causal=True, window=None, cap=None,
-                           bq=128, bk=128, kv_len=None,
-                           interpret: bool = True):
+                           bq=128, bk=128, kv_len=None):
     """q: (B, Hq, Sq, D); k/v: (B, Hkv, Skv, D) -> (B, Hq, Sq, D)."""
     b, hq, sq, d = q.shape
     hkv, skv = k.shape[1], k.shape[2]
@@ -129,5 +130,5 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=None, cap=None,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(q, k, v)

@@ -11,14 +11,11 @@ from .ref import flash_attention_ref
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "cap",
-                                             "bq", "bk", "impl",
-                                             "interpret"))
+                                             "bq", "bk", "impl"))
 def flash_attention(q, k, v, *, causal=True, window=None, cap=None,
-                    bq=128, bk=128, impl: str = "pallas",
-                    interpret: bool = True):
+                    bq=128, bk=128, impl: str = "pallas"):
     if impl == "ref":
         return flash_attention_ref(q, k, v, causal=causal, window=window,
                                    cap=cap)
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                  cap=cap, bq=bq, bk=bk,
-                                  interpret=interpret)
+                                  cap=cap, bq=bq, bk=bk)

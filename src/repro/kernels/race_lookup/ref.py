@@ -2,7 +2,6 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 
@@ -17,10 +16,12 @@ def race_lookup_ref(fp_table, val_table, queries, bucket_idx):
         axis=1)                                          # (NQ, 2*NSLOT, V)
     hit = (fps == queries[:, None]) & (fps != 0)
     first = jnp.argmax(hit, axis=1)
-    onehot = jax.nn.one_hot(first, fps.shape[1], dtype=vals.dtype) \
-        * jnp.any(hit, axis=1, keepdims=True).astype(vals.dtype)
-    values = jnp.einsum("qs,qsv->qv", onehot, vals)
-    found = jnp.any(hit, axis=1).astype(jnp.int32)
+    found = jnp.any(hit, axis=1)
+    # a gather, not a one-hot matmul: exact on every backend, whatever
+    # precision the device's matmul uses for f32
+    picked = jnp.take_along_axis(vals, first[:, None, None], axis=1)[:, 0]
+    values = jnp.where(found[:, None], picked, 0).astype(vals.dtype)
+    found = found.astype(jnp.int32)
     return values, found
 
 

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 
 def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, state_ref, *,
                 chunk):
@@ -68,8 +70,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, state_ref, *,
         preferred_element_type=jnp.float32)
 
 
-def wkv_pallas(r, k, v, logw, u, *, chunk: int = 16,
-               interpret: bool = True):
+def wkv_pallas(r, k, v, logw, u, *, chunk: int = 16):
     """r,k,logw: (B,H,S,dk); v: (B,H,S,dv); u: (H,dk).
 
     Returns o: (B,H,S,dv). State starts at zero (prefill semantics); the
@@ -98,6 +99,6 @@ def wkv_pallas(r, k, v, logw, u, *, chunk: int = 16,
         out_specs=pl.BlockSpec((1, c, dv), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, s, dv), r.dtype),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(flat(r, dk), flat(k, dk), flat(v, dv), flat(logw, dk), u)
     return o.reshape(b, h, s, dv)

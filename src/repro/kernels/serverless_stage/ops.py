@@ -74,19 +74,18 @@ def unpack_plan(lengths: np.ndarray, lmax: int,
     return src_row, valid
 
 
-@functools.partial(jax.jit, static_argnames=("impl", "interpret", "chunk"))
+@functools.partial(jax.jit, static_argnames=("impl", "chunk"))
 def chunk_gather(src, src_row, valid, impl: str = "pallas",
-                 interpret: bool = True, chunk: int = CHUNK):
+                 chunk: int = CHUNK):
     """Dispatch to the Pallas kernel or the jnp oracle (``impl="ref"``)."""
     if impl == "ref":
         return chunk_gather_ref(src, src_row, valid, chunk=chunk)
-    return chunk_gather_pallas(src, src_row, valid, chunk=chunk,
-                               interpret=interpret)
+    return chunk_gather_pallas(src, src_row, valid, chunk=chunk)
 
 
 def stage_pack(payloads: np.ndarray, lengths: np.ndarray, *,
-               chunk: int = CHUNK, impl: str = "pallas",
-               interpret: bool = True) -> Tuple[np.ndarray, np.ndarray]:
+               chunk: int = CHUNK,
+               impl: str = "pallas") -> Tuple[np.ndarray, np.ndarray]:
     """Pack K ragged payloads into one contiguous slab.
 
     payloads: (K, Lmax) int32 (rows padded arbitrarily past their length);
@@ -104,14 +103,12 @@ def stage_pack(payloads: np.ndarray, lengths: np.ndarray, *,
         payloads = np.pad(payloads, ((0, 0), (0, pad)))
     src = payloads.reshape(k * cmax, chunk)
     src_row, valid = pack_plan(lengths, lmax, chunk)
-    slab = chunk_gather(src, src_row, valid, impl=impl,
-                        interpret=interpret, chunk=chunk)
+    slab = chunk_gather(src, src_row, valid, impl=impl, chunk=chunk)
     return np.asarray(slab, np.int32).reshape(-1), starts
 
 
 def stage_unpack(slab: np.ndarray, lengths: np.ndarray, lmax: int, *,
-                 chunk: int = CHUNK, impl: str = "pallas",
-                 interpret: bool = True) -> np.ndarray:
+                 chunk: int = CHUNK, impl: str = "pallas") -> np.ndarray:
     """Inverse of :func:`stage_pack`: slab -> (K, Lmax) int32 matrix with
     each row's tail (beyond its length) zeroed."""
     lengths = np.asarray(lengths)
@@ -127,6 +124,5 @@ def stage_unpack(slab: np.ndarray, lengths: np.ndarray, lmax: int, *,
     src = slab[:total_chunks * chunk].reshape(total_chunks, chunk) \
         if total_chunks else np.zeros((1, chunk), np.int32)
     src_row, valid = unpack_plan(lengths, lmax, chunk)
-    out = chunk_gather(src, src_row, valid, impl=impl,
-                       interpret=interpret, chunk=chunk)
+    out = chunk_gather(src, src_row, valid, impl=impl, chunk=chunk)
     return np.asarray(out, np.int32).reshape(k, cmax * chunk)[:, :lmax]

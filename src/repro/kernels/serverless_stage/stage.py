@@ -17,6 +17,8 @@ DMAs exactly one CHUNK-wide block (the same discipline as the scalar
 race-lookup kernel's per-bucket BlockSpecs), masks the ragged tail on the
 VPU, and writes one output chunk. CHUNK defaults to 128 int32 lanes
 (= 512 B), the TPU lane width, so every copy is a full-lane vector op.
+Source and output are viewed as ``(N, 1, chunk)`` so that a one-chunk
+block's last two dims equal the array's, as the TPU tiling requires.
 """
 
 from __future__ import annotations
@@ -28,6 +30,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 CHUNK = 128                     # int32 elements per staged chunk (512 B)
 
 
@@ -37,12 +41,12 @@ def _gather_kernel(src_row_ref, valid_ref, src_ref, out_ref, *, chunk):
     routing hole)."""
     j = pl.program_id(0)
     v = valid_ref[j]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, chunk), 2)
     out_ref[...] = jnp.where(lane < v, src_ref[...], 0)
 
 
 def chunk_gather_pallas(src, src_row, valid, *, chunk: int = CHUNK,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """Gather ``len(src_row)`` chunks out of ``src``.
 
     src: (NSRC, chunk) int32 — chunk-granular view of the source buffer;
@@ -55,18 +59,22 @@ def chunk_gather_pallas(src, src_row, valid, *, chunk: int = CHUNK,
     nout = src_row.shape[0]
     if nout == 0:
         return jnp.zeros((0, chunk), jnp.int32)
+    nsrc = src.shape[0]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(nout,),
         in_specs=[
-            pl.BlockSpec((1, chunk), lambda j, rows, valid: (rows[j], 0)),
+            pl.BlockSpec((1, 1, chunk),
+                         lambda j, rows, valid: (rows[j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, chunk), lambda j, rows, valid: (j, 0)),
+        out_specs=pl.BlockSpec((1, 1, chunk),
+                               lambda j, rows, valid: (j, 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_gather_kernel, chunk=chunk),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nout, chunk), jnp.int32),
-        interpret=interpret,
-    )(src_row, valid, src)
+        out_shape=jax.ShapeDtypeStruct((nout, 1, chunk), jnp.int32),
+        interpret=interpret_mode() if interpret is None else interpret,
+    )(src_row, valid, src.reshape(nsrc, 1, chunk))
+    return out.reshape(nout, chunk)

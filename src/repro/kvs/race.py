@@ -86,7 +86,10 @@ def _h1(k: int, nb: int) -> int:
     return (k * 2654435761 + 7) % nb
 
 def _h2(k: int, nb: int) -> int:
-    return (k * 40503 + 0x9E3779B9) % nb
+    # the high bits of a second multiplicative hash: a linear function of
+    # k taken mod nb, like _h1, would make the second choice a function of
+    # the first, and two-choice placement would overflow at half load
+    return (((k * 0x85EBCA6B + 0x9E3779B9) & 0xFFFFFFFF) >> 8) % nb
 
 def _fp(k: int) -> int:
     fp = (k * 2246822519 + 1) & 0xFFFFFFFF
@@ -511,8 +514,9 @@ class DeviceRaceTable:
         self._val[b, s, :len(value)] = value
         self._loads[b] += 1
 
-    def lookup_batch(self, keys: np.ndarray, impl: str = "pallas"):
-        from repro.kernels.race_lookup.ops import race_lookup
+    def prep(self, keys: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        """(fingerprints (NQ,) i32, candidate bucket rows (NQ, 2) i32) of
+        ``keys`` — the kernel's query operands."""
         keys = np.asarray(keys)
         fps = np.array([(_fp(int(k)) & 0x7FFFFFFF) or 1 for k in keys],
                        np.int32)
@@ -520,6 +524,15 @@ class DeviceRaceTable:
             [[_h1(int(k), self.n_buckets) for k in keys],
              [_h2(int(k), self.n_buckets) for k in keys]],
             axis=1).astype(np.int32)
+        return fps, bidx
+
+    def tables(self) -> Tuple[np.ndarray, np.ndarray]:
+        """(fp (NB, NSLOT) i32, val (NB, NSLOT, VDIM) f32) host tables."""
+        return self._fp, self._val
+
+    def lookup_batch(self, keys: np.ndarray, impl: str = "pallas"):
+        from repro.kernels.race_lookup.ops import race_lookup
+        fps, bidx = self.prep(keys)
         return race_lookup(self._fp, self._val, fps, bidx, impl=impl)
 
 
@@ -546,17 +559,24 @@ class ShardedDeviceRaceTable:
     def insert(self, key: int, value: np.ndarray) -> None:
         self.shards[self.shard_of(key)].insert(key, value)
 
+    def prep(self, keys: np.ndarray
+             ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """(fingerprints, intra-shard bucket rows (NQ, 2), owning shard
+        (NQ,)) of ``keys``; every shard shares one bucket geometry."""
+        fps, bidx = self.shards[0].prep(keys)
+        sidx = np.array([self.shard_of(int(k)) for k in np.asarray(keys)],
+                        np.int32)
+        return fps, bidx, sidx
+
+    def tables(self) -> Tuple[np.ndarray, np.ndarray]:
+        """Per-shard tables stacked: (NS, NB, NSLOT) and (NS, NB, NSLOT,
+        VDIM)."""
+        return (np.stack([s._fp for s in self.shards]),
+                np.stack([s._val for s in self.shards]))
+
     def lookup_batch(self, keys: np.ndarray, impl: str = "pallas"):
         from repro.kernels.race_lookup.ops import race_lookup_sharded
-        keys = np.asarray(keys)
-        fps = np.array([(_fp(int(k)) & 0x7FFFFFFF) or 1 for k in keys],
-                       np.int32)
-        bidx = np.stack(
-            [[_h1(int(k), self.n_buckets) for k in keys],
-             [_h2(int(k), self.n_buckets) for k in keys]],
-            axis=1).astype(np.int32)
-        sidx = np.array([self.shard_of(int(k)) for k in keys], np.int32)
-        fp_tables = np.stack([s._fp for s in self.shards])
-        val_tables = np.stack([s._val for s in self.shards])
+        fps, bidx, sidx = self.prep(keys)
+        fp_tables, val_tables = self.tables()
         return race_lookup_sharded(fp_tables, val_tables, fps, bidx, sidx,
                                    impl=impl)

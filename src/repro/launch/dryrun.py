@@ -28,7 +28,6 @@ from repro.distributed import (batch_specs, cache_specs,      # noqa: E402
                                param_specs)
 from repro.distributed.shardings import opt_state_specs      # noqa: E402
 from repro.launch.hlo_stats import collective_stats          # noqa: E402
-from repro.launch.mesh import set_mesh                       # noqa: E402
 from repro.launch.mesh import make_production_mesh           # noqa: E402
 from repro.launch.steps import (input_specs, make_decode_step,  # noqa: E402
                                 make_prefill_step, make_train_step)
@@ -80,7 +79,7 @@ def _analyze(cfg, shape_name, multi_pod):
     spec = input_specs(cfg, shape_name)
     kind, args = spec["kind"], spec["args"]
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         pspecs = param_specs(cfg, args[0], mesh)
         if kind == "train":
             fn = make_train_step(cfg)

@@ -11,23 +11,9 @@ from __future__ import annotations
 import jax
 
 
-def set_mesh(mesh):
-    """Version compat: ``jax.set_mesh(mesh)`` context where available;
-    on older jax the Mesh object itself is the ambient-mesh context
-    manager (``with mesh:``)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 def _make_mesh(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; older jax means every axis
-    # is implicitly Auto, so simply omit the kwarg there.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

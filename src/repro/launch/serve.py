@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, get_smoke_config
 from repro.elastic import ExecutablePool
+from repro.launch.cache import enable_compile_cache
 from repro.launch.steps import make_decode_step
 from repro.models import init_decode_cache, init_params, prefill
 
@@ -74,6 +75,7 @@ def main():
     ap.add_argument("--steps", type=int, default=16)
     ap.add_argument("--replicas", type=int, default=2)
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     params = init_params(cfg, jax.random.PRNGKey(0))
     pool = ExecutablePool()

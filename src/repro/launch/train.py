@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, get_smoke_config
 from repro.data import SyntheticLM, make_batch_iterator
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import make_train_step
 from repro.models import init_params
@@ -80,6 +81,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--lr", type=float, default=3e-3)
     args = ap.parse_args()
+    enable_compile_cache()
     losses = run(args.arch, args.smoke, args.steps, args.batch, args.seq,
                  args.ckpt_dir, lr=args.lr)
     print(f"first loss {losses[0]:.4f} -> last loss {losses[-1]:.4f}")

@@ -77,7 +77,7 @@ def slab_capacity_bytes(group: int, max_payload_bytes: int,
 
 
 def encode_slab(payloads: Sequence[np.ndarray], *, seq: int = 0,
-                chunk: int = CHUNK, interpret: bool = True) -> np.ndarray:
+                chunk: int = CHUNK) -> np.ndarray:
     """Pack byte payloads into the self-describing slab (uint8 array).
 
     ``seq`` is the slab's position within its hop: slabs can be delivered
@@ -93,7 +93,7 @@ def encode_slab(payloads: Sequence[np.ndarray], *, seq: int = 0,
         padded = np.zeros(elem_lens[i] * 4, np.uint8)
         padded[:byte_lens[i]] = np.asarray(p, np.uint8)
         mat[i, :elem_lens[i]] = padded.view(np.int32)
-    body, _ = stage_pack(mat, elem_lens, chunk=chunk, interpret=interpret)
+    body, _ = stage_pack(mat, elem_lens, chunk=chunk)
     hdr = np.zeros(_header_chunks(k, chunk) * chunk, np.int32)
     hdr[0] = k
     hdr[1] = seq
@@ -101,8 +101,8 @@ def encode_slab(payloads: Sequence[np.ndarray], *, seq: int = 0,
     return np.concatenate([hdr, body]).view(np.uint8)
 
 
-def decode_slab(raw: np.ndarray, *, chunk: int = CHUNK,
-                interpret: bool = True) -> Tuple[int, List[np.ndarray]]:
+def decode_slab(raw: np.ndarray, *,
+                chunk: int = CHUNK) -> Tuple[int, List[np.ndarray]]:
     """Inverse of :func:`encode_slab`: returns (seq, payloads)."""
     raw = np.ascontiguousarray(np.asarray(raw, np.uint8))
     if len(raw) % 4:
@@ -114,8 +114,7 @@ def decode_slab(raw: np.ndarray, *, chunk: int = CHUNK,
     elem_lens = np.array([-(-b // 4) for b in byte_lens], np.int32)
     lmax = max(int(elem_lens.max()) if k else 1, 1)
     body = ints[_header_chunks(k, chunk) * chunk:]
-    mat = stage_unpack(body, elem_lens, lmax, chunk=chunk,
-                       interpret=interpret)
+    mat = stage_unpack(body, elem_lens, lmax, chunk=chunk)
     out = []
     for i in range(k):
         row = np.ascontiguousarray(mat[i, :max(int(elem_lens[i]), 1)])
@@ -184,7 +183,7 @@ class ChainRunner:
                  pool: ContainerPool, transport: str = "krcore",
                  slab_payloads: int = 16, chunk: int = CHUNK,
                  standby: Optional[Dict[str, str]] = None,
-                 base_port: int = 7100, interpret: bool = True):
+                 base_port: int = 7100):
         self.cluster = cluster
         self.env = cluster.env
         self.registry = registry
@@ -194,7 +193,6 @@ class ChainRunner:
         self.chunk = chunk
         self.standby = dict(standby or {})
         self._next_port = base_port
-        self.interpret = interpret
         #: per-node listener cache: dst node -> Listener (long-lived)
         self._listeners: Dict[str, Listener] = {}
         #: sender-session cache: (src, dst, port) -> Session
@@ -300,8 +298,7 @@ class ChainRunner:
         # pack: one staging-kernel pass over all groups (modeled as a
         # single aggregated copy of the hop's bytes)
         t0 = env.now
-        slabs = [encode_slab(g, seq=i, chunk=self.chunk,
-                             interpret=self.interpret)
+        slabs = [encode_slab(g, seq=i, chunk=self.chunk)
                  for i, g in enumerate(groups)]
         total = sum(len(s) for s in slabs)
         yield env.timeout(cm.memcpy_us(total))
@@ -332,8 +329,7 @@ class ChainRunner:
         msgs = yield from listener.recv_n(len(groups))
         out: List[Optional[List[np.ndarray]]] = [None] * len(groups)
         for msg in msgs:
-            seq, group = decode_slab(msg.payload, chunk=self.chunk,
-                                     interpret=self.interpret)
+            seq, group = decode_slab(msg.payload, chunk=self.chunk)
             out[seq] = group        # slabs reassemble by header sequence
         yield env.timeout(cm.memcpy_us(total))       # unpack pass
         hop.drain_us += env.now - t0

@@ -208,3 +208,18 @@ def test_race_lookup_sharded_empty_and_device_table():
     assert np.array(got_f)[:59].all() and not np.array(got_f)[59:].any()
     for i, k in enumerate(range(1, 60)):
         np.testing.assert_allclose(np.array(got_v)[i], vals[k], atol=1e-6)
+
+
+def test_device_table_two_choice_loads_to_half():
+    """The two candidate buckets are independent hashes: at load 0.5 no
+    bucket overflows (with a second hash that is a function of the first,
+    each key's two choices form a fixed pair and this load overflows)."""
+    from repro.kvs.race import ShardedDeviceRaceTable
+
+    table = ShardedDeviceRaceTable(n_shards=13, n_buckets=373, nslot=8,
+                                   vdim=1)
+    keys = np.random.default_rng(0).choice(2 ** 30, 20_000, replace=False)
+    for k in keys.tolist():
+        table.insert(k + 1, np.zeros(1, np.float32))
+    loads = np.stack([s._loads for s in table.shards])
+    assert loads.sum() == 20_000 and loads.max() <= 8

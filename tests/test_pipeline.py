@@ -57,6 +57,9 @@ print("PIPELINE_OK")
 
 
 def test_pipeline_matches_sequential():
+    # virtual host devices on purpose: the child never reaches for an
+    # accelerator that this process may hold
     out = subprocess.run([sys.executable, "-c", CODE],
-                         capture_output=True, text=True, timeout=600)
+                         capture_output=True, text=True, timeout=600,
+                         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert "PIPELINE_OK" in out.stdout, out.stdout + out.stderr
