@@ -39,7 +39,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 class PoolEntry:
     value: Any
     kind: str                  # "generic" | "specialized"
-    compile_s: float
     uses: int = 0
 
 
@@ -60,15 +59,14 @@ class ExecutablePool:
         self._coarsen = coarsen
         self.max_entries = max_entries
         self.stat_hits = 0
-        self.stat_generic_hits = 0
         self.stat_misses = 0
 
-    def put(self, key, value, kind="specialized", compile_s=0.0):
+    def put(self, key, value, kind="specialized"):
         with self._lock:
             if len(self._entries) >= self.max_entries:
                 lru = min(self._entries.items(), key=lambda kv: kv[1].uses)
                 del self._entries[lru[0]]
-            self._entries[key] = PoolEntry(value, kind, compile_s)
+            self._entries[key] = PoolEntry(value, kind)
 
     def get(self, key) -> Tuple[str, Optional[Any]]:
         with self._lock:
@@ -81,7 +79,6 @@ class ExecutablePool:
             ent = self._entries.get(coarse)
             if ent is not None:
                 ent.uses += 1
-                self.stat_generic_hits += 1
                 return "generic", ent.value
             self.stat_misses += 1
             return "miss", None
@@ -93,9 +90,7 @@ class ExecutablePool:
                 return
 
         def work():
-            t0 = time.time()
-            value = builder()
-            self.put(key, value, "specialized", time.time() - t0)
+            self.put(key, builder(), "specialized")
             with self._lock:
                 self._inflight.pop(key, None)
 
@@ -231,10 +226,7 @@ class ElasticTrainer:
     def prewarm(self) -> None:
         """Boot-time ladder compile (the statically-initialized DCQPs)."""
         for n in self._ladder:
-            key = ("ladder", n)
-            t0 = time.time()
-            self.pool.put(key, self._builder(n)(), kind="generic",
-                          compile_s=time.time() - t0)
+            self.pool.put(("ladder", n), self._builder(n)(), kind="generic")
 
     def scale_to(self, n: int) -> Dict:
         """Elastic resize; returns the timing event (the paper's metric)."""

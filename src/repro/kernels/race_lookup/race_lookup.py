@@ -62,6 +62,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.kernels import interpret_mode
 
 #: padded VMEM bytes (see :func:`table_vmem_bytes`) up to which a table
@@ -379,6 +380,13 @@ def race_lookup_pallas_sharded(fp_tables, val_tables, queries, bucket_idx,
     input order: :func:`group_by_shard`, then
     :func:`sharded_lookup_call`, then a scatter back to input order. Not
     jit-wrapped — the grouping is data-dependent.
+
+    Spans (:mod:`repro.obs`), in order: ``race.group`` (with the slots,
+    the padding among them and QCAP), ``race.to_device`` (tables and
+    grouped queries, until they are on the device), ``race.kernel``
+    (dispatch), ``race.to_host`` (the padded answers back, which waits
+    for the kernel), ``race.scatter``, and ``race.to_device`` again (the
+    answers in input order).
     """
     ns = fp_tables.shape[0]
     vdim = val_tables.shape[-1]
@@ -386,18 +394,27 @@ def race_lookup_pallas_sharded(fp_tables, val_tables, queries, bucket_idx,
     if nq == 0:
         return (jnp.zeros((0, vdim), val_tables.dtype),
                 jnp.zeros((0,), jnp.int32))
-    q_g, b_g, pos, qblock = group_by_shard(queries, bucket_idx, shard_idx,
-                                           ns, qblock)
-    values, found = sharded_lookup_call(
-        jnp.asarray(fp_tables), jnp.asarray(val_tables), jnp.asarray(q_g),
-        jnp.asarray(b_g), qblock=qblock, interpret=interpret)
+    with obs.span("race.group") as add:
+        q_g, b_g, pos, qblock = group_by_shard(queries, bucket_idx,
+                                               shard_idx, ns, qblock)
+        add(slots=pos.size, padded_slots=pos.size - nq, qcap=pos.shape[1])
+    operands = (fp_tables, val_tables, q_g, b_g)
+    with obs.span("race.to_device", h2d_bytes=sum(
+            a.nbytes for a in operands if isinstance(a, np.ndarray))):
+        operands = jax.block_until_ready([jnp.asarray(a) for a in operands])
+    with obs.span("race.kernel", variant="sharded"):
+        values, found = sharded_lookup_call(*operands, qblock=qblock,
+                                            interpret=interpret)
 
-    # scatter grouped results back to input order
-    vals_g = np.asarray(values)
-    found_g = np.asarray(found)
-    valid = pos >= 0
-    out_v = np.zeros((nq, vdim), vals_g.dtype)
-    out_f = np.zeros(nq, np.int32)
-    out_v[pos[valid]] = vals_g[valid]
-    out_f[pos[valid]] = found_g[valid]
-    return jnp.asarray(out_v), jnp.asarray(out_f)
+    with obs.span("race.to_host"):
+        vals_g = np.asarray(values)
+        found_g = np.asarray(found)
+    with obs.span("race.scatter"):
+        valid = pos >= 0
+        out_v = np.zeros((nq, vdim), vals_g.dtype)
+        out_f = np.zeros(nq, np.int32)
+        out_v[pos[valid]] = vals_g[valid]
+        out_f[pos[valid]] = found_g[valid]
+    with obs.span("race.to_device", h2d_bytes=out_v.nbytes + out_f.nbytes):
+        return jax.block_until_ready((jnp.asarray(out_v),
+                                      jnp.asarray(out_f)))

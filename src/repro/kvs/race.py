@@ -39,6 +39,7 @@ bucket READs, retry when a concurrent insert moved it (torn-read guard).
 
 from __future__ import annotations
 
+import dataclasses
 import struct
 from typing import Generator, List, Optional, Tuple
 
@@ -490,8 +491,30 @@ class ShardClient:
         raise RuntimeError("insert_fenced: no claimable slot")
 
 
+@dataclasses.dataclass
+class LookupStats:
+    """Totals over every ``lookup_batch`` a device RACE table served,
+    counted by the spans of :mod:`repro.obs` as they open."""
+    calls: int = 0
+    #: keys asked for
+    keys: int = 0
+    #: bytes copied from the host to the device: tables, query operands
+    #: and (sharded) the answers
+    h2d_bytes: int = 0
+    #: query slots the sharded kernel ran: shards x padded queries a shard
+    slots: int = 0
+    #: of those, padding that holds no query
+    padded_slots: int = 0
+
+
 class DeviceRaceTable:
-    """TPU-resident RACE table: batched lookups via the Pallas kernel."""
+    """TPU-resident RACE table: batched lookups via the Pallas kernel.
+
+    Each ``lookup_batch`` is one :func:`repro.obs.request` counting into
+    ``stats``, with the spans ``race.prep`` (hashing), ``race.to_device``
+    (the tables and query operands shipped, until they are on the
+    device) and ``race.kernel`` (dispatch of the jitted lookup; the
+    answers stay on the device)."""
 
     def __init__(self, n_buckets: int = 1024, nslot: int = 8,
                  vdim: int = 128):
@@ -501,6 +524,7 @@ class DeviceRaceTable:
         self._fp = np.zeros((n_buckets, nslot), np.int32)
         self._val = np.zeros((n_buckets, nslot, vdim), np.float32)
         self._loads = np.zeros(n_buckets, np.int32)
+        self.stats = LookupStats()
 
     def insert(self, key: int, value: np.ndarray) -> None:
         b1, b2 = _h1(key, self.n_buckets), _h2(key, self.n_buckets)
@@ -531,9 +555,21 @@ class DeviceRaceTable:
         return self._fp, self._val
 
     def lookup_batch(self, keys: np.ndarray, impl: str = "pallas"):
-        from repro.kernels.race_lookup.ops import race_lookup
-        fps, bidx = self.prep(keys)
-        return race_lookup(self._fp, self._val, fps, bidx, impl=impl)
+        import jax
+
+        from repro import obs
+        from repro.kernels.race_lookup.ops import pallas_kernel, race_lookup
+        with obs.request(self.stats):
+            with obs.span("race.prep", keys=len(keys)):
+                fps, bidx = self.prep(keys)
+            operands = (self._fp, self._val, fps, bidx)
+            with obs.span("race.to_device",
+                          h2d_bytes=sum(a.nbytes for a in operands)):
+                operands = jax.block_until_ready(jax.device_put(operands))
+            variant = (pallas_kernel(self._fp.shape, self._val.shape)
+                       if impl == "pallas" else impl.removeprefix("pallas_"))
+            with obs.span("race.kernel", variant=variant):
+                return race_lookup(*operands, impl=impl)
 
 
 class ShardedDeviceRaceTable:
@@ -542,7 +578,12 @@ class ShardedDeviceRaceTable:
     lookups run through the SHARDED Pallas kernel
     (``race_lookup_sharded``): the grid gains a shard dimension and only
     ONE shard's table is resident per grid step, instead of the whole
-    multi-shard array pinned VMEM-resident at once."""
+    multi-shard array pinned VMEM-resident at once.
+
+    Each ``lookup_batch`` is one :func:`repro.obs.request` counting into
+    ``stats``: ``race.prep`` (hashing and shard routing) and
+    ``race.stack`` (the per-shard tables stacked) here, then those of
+    the sharded kernel path (``race_lookup_pallas_sharded``)."""
 
     def __init__(self, n_shards: int = 4, n_buckets: int = 256,
                  nslot: int = 8, vdim: int = 128):
@@ -552,6 +593,7 @@ class ShardedDeviceRaceTable:
         self.vdim = vdim
         self.shards = [DeviceRaceTable(n_buckets, nslot, vdim)
                        for _ in range(n_shards)]
+        self.stats = LookupStats()
 
     def shard_of(self, key: int) -> int:
         return shard_of_key(int(key), self.n_shards)
@@ -575,8 +617,12 @@ class ShardedDeviceRaceTable:
                 np.stack([s._val for s in self.shards]))
 
     def lookup_batch(self, keys: np.ndarray, impl: str = "pallas"):
+        from repro import obs
         from repro.kernels.race_lookup.ops import race_lookup_sharded
-        fps, bidx, sidx = self.prep(keys)
-        fp_tables, val_tables = self.tables()
-        return race_lookup_sharded(fp_tables, val_tables, fps, bidx, sidx,
-                                   impl=impl)
+        with obs.request(self.stats):
+            with obs.span("race.prep", keys=len(keys)):
+                fps, bidx, sidx = self.prep(keys)
+            with obs.span("race.stack"):
+                fp_tables, val_tables = self.tables()
+            return race_lookup_sharded(fp_tables, val_tables, fps, bidx,
+                                       sidx, impl=impl)

@@ -1,0 +1,157 @@
+"""Spans and counters (repro.obs) and the device RACE tables' use of them:
+call ids, counts into the right stats, and the spans read back from a
+profiler trace taken on the CPU."""
+
+import dataclasses
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+import jax
+
+from repro import obs
+from repro.kernels.race_lookup.ops import pallas_kernel
+from repro.kernels.race_lookup.race_lookup import group_by_shard
+from repro.kvs.race import DeviceRaceTable, LookupStats, ShardedDeviceRaceTable
+
+
+@dataclasses.dataclass
+class Counts:
+    calls: int = 0
+    keys: int = 0
+
+
+def traced(tmp_path, fn):
+    """Run ``fn`` under a profiler session; return its result and the
+    host events whose name starts with ``race.`` or ``t.``, in time
+    order, as (name, {arg: value})."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = next(Path(tmp_path).rglob("*.xplane.pb"))
+    events = []
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("race.", "t.")):
+                    events.append((e.start_ns, e.name, dict(e.stats)))
+    return out, [(n, a) for _, n, a in sorted(events, key=lambda x: x[0])]
+
+
+def test_nested_requests_keep_their_own_call_and_stats(tmp_path):
+    outer, inner = Counts(), Counts()
+
+    def work():
+        with obs.request(outer):
+            with obs.span("t.a", keys=3):
+                pass
+            with obs.request(inner):
+                with obs.span("t.b", keys=5):
+                    pass
+            with obs.span("t.c", keys=7):
+                pass
+        with obs.span("t.d", keys=11):       # outside any request
+            pass
+
+    _, events = traced(tmp_path, work)
+    assert [n for n, _ in events] == ["t.a", "t.b", "t.c", "t.d"]
+    a, b, c, d = (args for _, args in events)
+    assert a["call"] == c["call"] != b["call"]
+    assert "call" not in d and d["keys"] == 11
+    assert (outer.calls, outer.keys) == (1, 3 + 7)
+    assert (inner.calls, inner.keys) == (1, 5)
+
+
+def test_span_args_read_back_and_only_stats_fields_count(tmp_path):
+    stats = LookupStats()
+
+    def work():
+        with obs.request(stats):
+            with obs.span("t.x", h2d_bytes=123, variant="tiled") as add:
+                add(slots=40, qcap=8)
+
+    _, events = traced(tmp_path, work)
+    [(name, args)] = events
+    assert name == "t.x"
+    assert args == {"call": args["call"], "h2d_bytes": 123,
+                    "variant": "tiled", "slots": 40, "qcap": 8}
+    # qcap and variant are no fields of the stats: arguments only
+    assert stats == LookupStats(calls=1, h2d_bytes=123, slots=40)
+
+
+def test_spans_outside_a_trace_still_count():
+    stats = LookupStats()
+    for _ in range(3):
+        with obs.request(stats):
+            with obs.span("t.y", keys=2) as add:
+                add(padded_slots=1)
+    assert stats == LookupStats(calls=3, keys=6, padded_slots=3)
+
+
+def _filled(table, keys, vdim, seed):
+    rng = np.random.default_rng(seed)
+    for k in keys:
+        table.insert(int(k), rng.standard_normal(vdim).astype(np.float32))
+
+
+@pytest.mark.parametrize("impl", ["pallas", "pallas_scalar"])
+def test_flat_table_spans_and_stats(tmp_path, impl):
+    nb, nslot, vdim = 64, 8, 32
+    table = DeviceRaceTable(n_buckets=nb, nslot=nslot, vdim=vdim)
+    _filled(table, range(1, 200), vdim, 0)
+    keys = np.arange(150, 250)
+    (v, f), events = traced(tmp_path,
+                            lambda: table.lookup_batch(keys, impl=impl))
+    assert np.asarray(f)[:50].all() and not np.asarray(f)[50:].any()
+    assert [n for n, _ in events] == ["race.prep", "race.to_device",
+                                      "race.kernel"]
+    assert len({a["call"] for _, a in events}) == 1
+    variant = (pallas_kernel((nb, nslot), (nb, nslot, vdim))
+               if impl == "pallas" else "scalar")
+    assert events[2][1]["variant"] == variant
+    h2d = nb * nslot * 4 + nb * nslot * vdim * 4 + len(keys) * (4 + 8)
+    assert events[1][1]["h2d_bytes"] == h2d
+    assert table.stats == LookupStats(calls=1, keys=len(keys),
+                                      h2d_bytes=h2d)
+
+
+def test_sharded_table_spans_and_stats(tmp_path):
+    ns, nb, nslot, vdim = 3, 32, 8, 32
+    table = ShardedDeviceRaceTable(n_shards=ns, n_buckets=nb, nslot=nslot,
+                                   vdim=vdim)
+    _filled(table, range(1, 300), vdim, 1)
+    batches = [np.arange(250, 350), np.arange(1, 41), np.arange(7, 8)]
+    (v, f), events = traced(tmp_path,
+                            lambda: table.lookup_batch(batches[0]))
+    assert np.asarray(f)[:50].all() and not np.asarray(f)[50:].any()
+    assert [n for n, _ in events] == [
+        "race.prep", "race.stack", "race.group", "race.to_device",
+        "race.kernel", "race.to_host", "race.scatter", "race.to_device"]
+    assert len({a["call"] for _, a in events}) == 1
+    assert events[4][1]["variant"] == "sharded"
+    for b in batches[1:]:
+        table.lookup_batch(b)
+
+    # the same totals recomputed from the shapes and the grouping
+    table_bytes = ns * nb * nslot * (4 + vdim * 4)
+    slots = h2d = 0
+    for b in batches:
+        _, _, sidx = table.prep(b)
+        _, _, pos, _ = group_by_shard(np.zeros(len(b), np.int32),
+                                      np.zeros((len(b), 2), np.int32),
+                                      sidx, ns, 64)
+        assert pos.shape[0] == ns
+        slots += pos.size
+        h2d += table_bytes + pos.size * (4 + 8) + len(b) * (vdim * 4 + 4)
+    keys = sum(len(b) for b in batches)
+    assert table.stats == LookupStats(calls=3, keys=keys, h2d_bytes=h2d,
+                                      slots=slots,
+                                      padded_slots=slots - keys)
+    group = events[2][1]
+    assert group["slots"] - group["padded_slots"] == len(batches[0])
+    assert group["slots"] == ns * group["qcap"]
