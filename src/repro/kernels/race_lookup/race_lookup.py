@@ -382,8 +382,9 @@ def race_lookup_pallas_sharded(fp_tables, val_tables, queries, bucket_idx,
     jit-wrapped — the grouping is data-dependent.
 
     Spans (:mod:`repro.obs`), in order: ``race.group`` (with the slots,
-    the padding among them and QCAP), ``race.to_device`` (tables and
-    grouped queries, until they are on the device), ``race.kernel``
+    the padding among them and QCAP), ``race.to_device`` (the grouped
+    queries, and the tables where they are passed as host arrays, until
+    they are on the device; device arrays count no bytes), ``race.kernel``
     (dispatch), ``race.to_host`` (the padded answers back, which waits
     for the kernel), ``race.scatter``, and ``race.to_device`` again (the
     answers in input order).

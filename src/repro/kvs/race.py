@@ -498,32 +498,79 @@ class LookupStats:
     calls: int = 0
     #: keys asked for
     keys: int = 0
-    #: bytes copied from the host to the device: tables, query operands
-    #: and (sharded) the answers
+    #: bytes copied from the host to the device: table ships, query
+    #: operands and (sharded) the answers
     h2d_bytes: int = 0
+    #: whole-table copies to the device: one on the first lookup and on
+    #: each lookup that follows an insert, none while the device copy is
+    #: current
+    table_ships: int = 0
     #: query slots the sharded kernel ran: shards x padded queries a shard
     slots: int = 0
     #: of those, padding that holds no query
     padded_slots: int = 0
 
 
-class DeviceRaceTable:
+class _Resident:
+    """A device copy of a table's host arrays (``tables()``), kept with
+    the ``version`` of the host arrays it holds: a lookup ships the
+    tables only when an insert has moved ``version`` since the last
+    ship."""
+
+    _dev: Optional[Tuple] = None
+    _dev_version = -1
+
+    def _ship_if_stale(self) -> Tuple:
+        """The device copy of ``tables()``; shipped first, in a
+        ``race.to_device`` span of its own counting ``table_ships``, if
+        ``version`` has moved since the last ship."""
+        version = self.version
+        if self._dev_version != version:
+            import jax
+
+            from repro import obs
+            self._dev = None        # free the stale copy before the ship
+            host = self.tables()
+            with obs.span("race.to_device", table_ships=1,
+                          h2d_bytes=sum(a.nbytes for a in host)):
+                self._dev = jax.block_until_ready(jax.device_put(host))
+            self._dev_version = version
+        return self._dev
+
+
+class DeviceRaceTable(_Resident):
     """TPU-resident RACE table: batched lookups via the Pallas kernel.
+
+    Residency: the host arrays (``tables()``) are the table; ``insert``
+    writes them and bumps ``version``. The device holds one copy of them
+    between lookups. A lookup ships the whole table only when ``version``
+    has moved since the last ship (the first lookup, or the first after
+    any insert); otherwise only the query operands cross to the device.
 
     Each ``lookup_batch`` is one :func:`repro.obs.request` counting into
     ``stats``, with the spans ``race.prep`` (hashing), ``race.to_device``
-    (the tables and query operands shipped, until they are on the
+    (the table, only when it ships, with ``table_ships``),
+    ``race.to_device`` (the query operands, until they are on the
     device) and ``race.kernel`` (dispatch of the jitted lookup; the
-    answers stay on the device)."""
+    answers stay on the device).
+
+    ``fp`` and ``val`` are host arrays to hold the table in (a sharded
+    table's shards are views into its stacked arrays); fresh zeros if
+    not given."""
 
     def __init__(self, n_buckets: int = 1024, nslot: int = 8,
-                 vdim: int = 128):
+                 vdim: int = 128, *, fp: Optional[np.ndarray] = None,
+                 val: Optional[np.ndarray] = None):
         self.n_buckets = n_buckets
         self.nslot = nslot
         self.vdim = vdim
-        self._fp = np.zeros((n_buckets, nslot), np.int32)
-        self._val = np.zeros((n_buckets, nslot, vdim), np.float32)
+        self._fp = (np.zeros((n_buckets, nslot), np.int32)
+                    if fp is None else fp)
+        self._val = (np.zeros((n_buckets, nslot, vdim), np.float32)
+                     if val is None else val)
         self._loads = np.zeros(n_buckets, np.int32)
+        #: inserts so far: the version of the host tables
+        self.version = 0
         self.stats = LookupStats()
 
     def insert(self, key: int, value: np.ndarray) -> None:
@@ -537,6 +584,7 @@ class DeviceRaceTable:
         self._fp[b, s] = np.int32(_fp(key) & 0x7FFFFFFF) or 1
         self._val[b, s, :len(value)] = value
         self._loads[b] += 1
+        self.version += 1
 
     def prep(self, keys: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """(fingerprints (NQ,) i32, candidate bucket rows (NQ, 2) i32) of
@@ -562,17 +610,19 @@ class DeviceRaceTable:
         with obs.request(self.stats):
             with obs.span("race.prep", keys=len(keys)):
                 fps, bidx = self.prep(keys)
-            operands = (self._fp, self._val, fps, bidx)
+            fp_table, val_table = self._ship_if_stale()
             with obs.span("race.to_device",
-                          h2d_bytes=sum(a.nbytes for a in operands)):
-                operands = jax.block_until_ready(jax.device_put(operands))
+                          h2d_bytes=fps.nbytes + bidx.nbytes):
+                fps, bidx = jax.block_until_ready(
+                    jax.device_put((fps, bidx)))
             variant = (pallas_kernel(self._fp.shape, self._val.shape)
                        if impl == "pallas" else impl.removeprefix("pallas_"))
             with obs.span("race.kernel", variant=variant):
-                return race_lookup(*operands, impl=impl)
+                return race_lookup(fp_table, val_table, fps, bidx,
+                                   impl=impl)
 
 
-class ShardedDeviceRaceTable:
+class ShardedDeviceRaceTable(_Resident):
     """Multi-shard TPU-resident RACE table: the device analogue of the
     dkv shard map. Per-shard tables share one geometry and batched
     lookups run through the SHARDED Pallas kernel
@@ -580,10 +630,20 @@ class ShardedDeviceRaceTable:
     ONE shard's table is resident per grid step, instead of the whole
     multi-shard array pinned VMEM-resident at once.
 
+    Residency: the host tables are held stacked from construction, (NS,
+    NB, NSLOT) and (NS, NB, NSLOT, VDIM), and each shard's tables are
+    views into them, so an insert through this table or through a
+    shard's own ``insert`` writes the stacked arrays and moves
+    ``version``. The device holds one copy of the stacked tables between
+    lookups, shipped whole only when ``version`` has moved since the last
+    ship; otherwise only the grouped queries and the answers cross.
+
     Each ``lookup_batch`` is one :func:`repro.obs.request` counting into
-    ``stats``: ``race.prep`` (hashing and shard routing) and
-    ``race.stack`` (the per-shard tables stacked) here, then those of
-    the sharded kernel path (``race_lookup_pallas_sharded``)."""
+    ``stats``: ``race.prep`` (hashing and shard routing), then
+    ``race.to_device`` (the stacked tables, only when they ship, with
+    ``table_ships``) and ``race.stack`` (the resident stacked tables
+    handed to the kernel path) here, then those of the sharded kernel
+    path (``race_lookup_pallas_sharded``)."""
 
     def __init__(self, n_shards: int = 4, n_buckets: int = 256,
                  nslot: int = 8, vdim: int = 128):
@@ -591,9 +651,17 @@ class ShardedDeviceRaceTable:
         self.n_buckets = n_buckets
         self.nslot = nslot
         self.vdim = vdim
-        self.shards = [DeviceRaceTable(n_buckets, nslot, vdim)
-                       for _ in range(n_shards)]
+        self._fp = np.zeros((n_shards, n_buckets, nslot), np.int32)
+        self._val = np.zeros((n_shards, n_buckets, nslot, vdim), np.float32)
+        self.shards = [DeviceRaceTable(n_buckets, nslot, vdim,
+                                       fp=self._fp[i], val=self._val[i])
+                       for i in range(n_shards)]
         self.stats = LookupStats()
+
+    @property
+    def version(self) -> int:
+        """Inserts so far, over every shard."""
+        return sum(s.version for s in self.shards)
 
     def shard_of(self, key: int) -> int:
         return shard_of_key(int(key), self.n_shards)
@@ -611,10 +679,9 @@ class ShardedDeviceRaceTable:
         return fps, bidx, sidx
 
     def tables(self) -> Tuple[np.ndarray, np.ndarray]:
-        """Per-shard tables stacked: (NS, NB, NSLOT) and (NS, NB, NSLOT,
-        VDIM)."""
-        return (np.stack([s._fp for s in self.shards]),
-                np.stack([s._val for s in self.shards]))
+        """The per-shard host tables, stacked (no copy): (NS, NB, NSLOT)
+        and (NS, NB, NSLOT, VDIM)."""
+        return self._fp, self._val
 
     def lookup_batch(self, keys: np.ndarray, impl: str = "pallas"):
         from repro import obs
@@ -622,7 +689,8 @@ class ShardedDeviceRaceTable:
         with obs.request(self.stats):
             with obs.span("race.prep", keys=len(keys)):
                 fps, bidx, sidx = self.prep(keys)
+            self._ship_if_stale()
             with obs.span("race.stack"):
-                fp_tables, val_tables = self.tables()
+                fp_tables, val_tables = self._dev
             return race_lookup_sharded(fp_tables, val_tables, fps, bidx,
                                        sidx, impl=impl)

@@ -26,16 +26,19 @@ is their parent.
 
 The device RACE tables (``repro.kvs.race``) open one request per
 ``lookup_batch`` and count into their ``stats`` (``LookupStats``:
-``calls``, ``keys``, ``h2d_bytes``, ``slots``, ``padded_slots``). Their
-spans:
+``calls``, ``keys``, ``h2d_bytes``, ``table_ships``, ``slots``,
+``padded_slots``). Their spans:
 
 =================  ====================================================
 ``race.prep``      per-key hashing (and shard routing); ``keys``
-``race.stack``     sharded: the per-shard tables stacked into one array
+``race.stack``     sharded: the resident stacked tables handed to the
+                   kernel path (no copy)
 ``race.group``     sharded: queries grouped and padded per shard;
                    ``slots``, ``padded_slots``, ``qcap``
 ``race.to_device`` host-to-device copies, until they are on the device;
-                   ``h2d_bytes``
+                   ``h2d_bytes``. The whole table ships in a span of
+                   its own, with ``table_ships``, only on a lookup that
+                   follows an insert (or the first)
 ``race.kernel``    dispatch of the jitted lookup; ``variant`` is
                    ``scalar``, ``tiled``, ``sharded`` (or ``ref``)
 ``race.to_host``   sharded: the padded answers back, waiting for the

@@ -84,6 +84,14 @@ def test_span_args_read_back_and_only_stats_fields_count(tmp_path):
     assert stats == LookupStats(calls=1, h2d_bytes=123, slots=40)
 
 
+def _by_call(events):
+    """``events`` split into one list per request, in call order."""
+    calls = {}
+    for n, a in events:
+        calls.setdefault(a["call"], []).append((n, a))
+    return [calls[c] for c in sorted(calls)]
+
+
 def test_spans_outside_a_trace_still_count():
     stats = LookupStats()
     for _ in range(3):
@@ -105,19 +113,29 @@ def test_flat_table_spans_and_stats(tmp_path, impl):
     table = DeviceRaceTable(n_buckets=nb, nslot=nslot, vdim=vdim)
     _filled(table, range(1, 200), vdim, 0)
     keys = np.arange(150, 250)
-    (v, f), events = traced(tmp_path,
-                            lambda: table.lookup_batch(keys, impl=impl))
-    assert np.asarray(f)[:50].all() and not np.asarray(f)[50:].any()
-    assert [n for n, _ in events] == ["race.prep", "race.to_device",
-                                      "race.kernel"]
-    assert len({a["call"] for _, a in events}) == 1
+    answers, events = traced(tmp_path, lambda: [
+        table.lookup_batch(keys, impl=impl) for _ in range(2)])
+    for v, f in answers:
+        assert np.asarray(f)[:50].all() and not np.asarray(f)[50:].any()
+    calls = _by_call(events)
+    # the first lookup ships the table in a span of its own; the second
+    # finds it resident and ships the query operands alone
+    assert [[n for n, _ in c] for c in calls] == [
+        ["race.prep", "race.to_device", "race.to_device", "race.kernel"],
+        ["race.prep", "race.to_device", "race.kernel"]]
     variant = (pallas_kernel((nb, nslot), (nb, nslot, vdim))
                if impl == "pallas" else "scalar")
-    assert events[2][1]["variant"] == variant
-    h2d = nb * nslot * 4 + nb * nslot * vdim * 4 + len(keys) * (4 + 8)
-    assert events[1][1]["h2d_bytes"] == h2d
-    assert table.stats == LookupStats(calls=1, keys=len(keys),
-                                      h2d_bytes=h2d)
+    assert calls[0][3][1]["variant"] == calls[1][2][1]["variant"] == variant
+    table_bytes = nb * nslot * 4 + nb * nslot * vdim * 4
+    queries = len(keys) * (4 + 8)
+    assert calls[0][1][1]["h2d_bytes"] == table_bytes
+    assert calls[0][1][1]["table_ships"] == 1
+    assert calls[0][2][1]["h2d_bytes"] == calls[1][1][1]["h2d_bytes"] \
+        == queries
+    assert "table_ships" not in calls[1][1][1]
+    assert table.stats == LookupStats(calls=2, keys=2 * len(keys),
+                                      h2d_bytes=table_bytes + 2 * queries,
+                                      table_ships=1)
 
 
 def test_sharded_table_spans_and_stats(tmp_path):
@@ -126,20 +144,28 @@ def test_sharded_table_spans_and_stats(tmp_path):
                                    vdim=vdim)
     _filled(table, range(1, 300), vdim, 1)
     batches = [np.arange(250, 350), np.arange(1, 41), np.arange(7, 8)]
-    (v, f), events = traced(tmp_path,
-                            lambda: table.lookup_batch(batches[0]))
-    assert np.asarray(f)[:50].all() and not np.asarray(f)[50:].any()
-    assert [n for n, _ in events] == [
-        "race.prep", "race.stack", "race.group", "race.to_device",
-        "race.kernel", "race.to_host", "race.scatter", "race.to_device"]
-    assert len({a["call"] for _, a in events}) == 1
-    assert events[4][1]["variant"] == "sharded"
-    for b in batches[1:]:
-        table.lookup_batch(b)
+    answers, events = traced(tmp_path, lambda: [
+        table.lookup_batch(b) for b in batches[:2]])
+    f = np.asarray(answers[0][1])
+    assert f[:50].all() and not f[50:].any()
+    calls = _by_call(events)
+    # the first lookup ships the stacked tables before handing them out;
+    # the second finds them resident
+    hit = ["race.prep", "race.stack", "race.group", "race.to_device",
+           "race.kernel", "race.to_host", "race.scatter", "race.to_device"]
+    assert [[n for n, _ in c] for c in calls] == [
+        hit[:1] + ["race.to_device"] + hit[1:], hit]
+    assert calls[0][1][1]["table_ships"] == 1
+    assert all("table_ships" not in a for _, a in calls[1])
+    assert calls[0][5][1]["variant"] == calls[1][4][1]["variant"] \
+        == "sharded"
+    table.lookup_batch(batches[2])
 
     # the same totals recomputed from the shapes and the grouping
     table_bytes = ns * nb * nslot * (4 + vdim * 4)
-    slots = h2d = 0
+    assert calls[0][1][1]["h2d_bytes"] == table_bytes
+    slots = 0
+    h2d = table_bytes
     for b in batches:
         _, _, sidx = table.prep(b)
         _, _, pos, _ = group_by_shard(np.zeros(len(b), np.int32),
@@ -147,11 +173,11 @@ def test_sharded_table_spans_and_stats(tmp_path):
                                       sidx, ns, 64)
         assert pos.shape[0] == ns
         slots += pos.size
-        h2d += table_bytes + pos.size * (4 + 8) + len(b) * (vdim * 4 + 4)
+        h2d += pos.size * (4 + 8) + len(b) * (vdim * 4 + 4)
     keys = sum(len(b) for b in batches)
     assert table.stats == LookupStats(calls=3, keys=keys, h2d_bytes=h2d,
-                                      slots=slots,
+                                      table_ships=1, slots=slots,
                                       padded_slots=slots - keys)
-    group = events[2][1]
+    group = calls[0][3][1]
     assert group["slots"] - group["padded_slots"] == len(batches[0])
     assert group["slots"] == ns * group["qcap"]
