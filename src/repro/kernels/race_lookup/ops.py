@@ -1,4 +1,4 @@
-"""jit'd public wrapper for the RACE-lookup kernels."""
+"""jit'd public wrappers for the RACE-lookup kernels."""
 
 from __future__ import annotations
 
@@ -11,7 +11,8 @@ import numpy as np
 from .race_lookup import (TILED_VMEM_BUDGET_BYTES, race_lookup_pallas,
                           race_lookup_pallas_sharded,
                           race_lookup_pallas_tiled, table_vmem_bytes)
-from .ref import race_lookup_ref
+from .pool import pool_lookup_pallas
+from .ref import pool_lookup_ref, race_lookup_ref
 
 
 def pallas_kernel(fp_shape, val_shape) -> str:
@@ -55,6 +56,32 @@ def race_lookup(fp_table, val_table, queries, bucket_idx,
         return race_lookup_pallas(fp_table, val_table, queries, bucket_idx)
     return race_lookup_pallas_tiled(fp_table, val_table, queries,
                                     bucket_idx, qblock=qblock)
+
+
+@functools.partial(jax.jit, static_argnames=("nslot", "impl"))
+def pool_lookup(index, keys, pool, qkeys, qfps, bucket_idx, *, nslot: int,
+                impl: str = "pallas"):
+    """RACE's two-level lookup over the pool layout (``PoolRaceTable``):
+    both candidate buckets from the index, the KV block of each
+    fingerprint match, the record whose stored key is the query key.
+
+    index (ceil(NB * 2 * NSLOT / 128), 1, 128) i32, keys (ceil(CAP /
+    128), 1, 128) i32, pool (CAP, 1, VDIM), qkeys (NQ,) i32, qfps (NQ,)
+    i32 8-bit fingerprints, bucket_idx (NQ, 2) i32 -> (values (NQ,
+    VDIM), found (NQ,) i32, the KV blocks fetched over the batch, an i32
+    scalar). ``impl``: ``"pallas"`` (the kernel, index and pool left in
+    HBM) or ``"ref"`` (the pure-jnp oracle)."""
+    if impl == "ref":
+        values, found, blocks = pool_lookup_ref(index, keys, pool, qkeys,
+                                                qfps, bucket_idx,
+                                                nslot=nslot)
+    elif impl == "pallas":
+        values, found, blocks = pool_lookup_pallas(index, keys, pool, qkeys,
+                                                   qfps, bucket_idx,
+                                                   nslot=nslot)
+    else:
+        raise ValueError(f"unknown impl {impl!r}")
+    return values, found, jnp.sum(blocks)
 
 
 def race_lookup_sharded(fp_tables, val_tables, queries, bucket_idx,

@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -23,6 +24,31 @@ def race_lookup_ref(fp_table, val_table, queries, bucket_idx):
     values = jnp.where(found[:, None], picked, 0).astype(vals.dtype)
     found = found.astype(jnp.int32)
     return values, found
+
+
+def pool_lookup_ref(index, keys, pool, qkeys, qfps, bucket_idx, *,
+                    nslot: int):
+    """Same contract as ``pool_lookup_pallas``: RACE's read over the pool
+    layout, as plain gathers. Each query's 2 * NSLOT candidate slots
+    (bucket 1's before bucket 2's), the occupied ones whose 8-bit
+    fingerprint matches (the blocks a reader fetches), of those the first
+    whose block's stored key is the query key, and its record."""
+    words = 2 * nslot
+    flat = index.reshape(-1)
+    off = (bucket_idx[:, :, None] * words
+           + 2 * jnp.arange(nslot)[None, None, :]).reshape(-1, 2 * nslot)
+    hi, lo = flat[off], flat[off + 1]                    # (NQ, 2*NSLOT)
+    fetched = (hi != 0) & (
+        jax.lax.shift_right_logical(hi, 24) == qfps[:, None])
+    stored = keys.reshape(-1)[lo]
+    match = fetched & (stored == qkeys[:, None])
+    found = jnp.any(match, axis=1)
+    row = jnp.take_along_axis(lo, jnp.argmax(match, axis=1)[:, None],
+                              axis=1)[:, 0]
+    records = pool.reshape(pool.shape[0], -1)[row]
+    values = jnp.where(found[:, None], records, 0).astype(pool.dtype)
+    return (values, found.astype(jnp.int32),
+            jnp.sum(fetched, axis=1).astype(jnp.int32))
 
 
 def make_table(n_buckets: int, nslot: int, vdim: int, keys, values,

@@ -18,6 +18,10 @@ Bucket layout in storage-node memory (binary, little-endian):
     bucket b, slot s at offset (b * NSLOT + s) * 16:
         [ fingerprint: u32 | vlen: u32 | value: 8B ]
 
+* :class:`PoolRaceTable` — RACE's own layout on the device: an index of
+  8-byte slots (fingerprint, length, pointer) over a pool of KV blocks
+  that carry their keys, both in HBM, read by the two-level pool kernel.
+
 * :class:`ShardClient` / :class:`ShardedDeviceRaceTable` — the
   shard-aware deployments: a store is ONE SHARD of the elastic dkv
   service (``src/repro/dkv``), addressed through the shard directory by
@@ -95,6 +99,30 @@ def _h2(k: int, nb: int) -> int:
 def _fp(k: int) -> int:
     fp = (k * 2246822519 + 1) & 0xFFFFFFFF
     return fp or 1
+
+
+def prep_keys(keys, n_buckets: int) -> Tuple[np.ndarray, np.ndarray]:
+    """The device tables' host hashing of ``keys``: (31-bit fingerprints
+    (NQ,) i32, never 0, and the two candidate buckets (NQ, 2) i32)."""
+    keys = np.asarray(keys)
+    fps = np.array([(_fp(int(k)) & 0x7FFFFFFF) or 1 for k in keys],
+                   np.int32)
+    bidx = np.stack(
+        [[_h1(int(k), n_buckets) for k in keys],
+         [_h2(int(k), n_buckets) for k in keys]],
+        axis=1).astype(np.int32)
+    return fps, bidx
+
+
+def _two_choice(loads, b1: int, b2: int, nslot: int) -> int:
+    """The bucket a new key goes to: the less loaded of its two (``b1`` on
+    a tie), or the other when that one is full."""
+    b = b1 if loads[b1] <= loads[b2] else b2
+    if loads[b] >= nslot:
+        b = b2 if b == b1 else b1
+        if loads[b] >= nslot:
+            raise RuntimeError("bucket overflow")
+    return b
 
 
 class RaceKVStore:
@@ -509,6 +537,9 @@ class LookupStats:
     slots: int = 0
     #: of those, padding that holds no query
     padded_slots: int = 0
+    #: pool layout: KV blocks the kernel fetched (true and false
+    #: fingerprint matches), summed on the device; ``int()`` reads it
+    blocks: int = 0
 
 
 class _Resident:
@@ -574,12 +605,8 @@ class DeviceRaceTable(_Resident):
         self.stats = LookupStats()
 
     def insert(self, key: int, value: np.ndarray) -> None:
-        b1, b2 = _h1(key, self.n_buckets), _h2(key, self.n_buckets)
-        b = b1 if self._loads[b1] <= self._loads[b2] else b2
-        if self._loads[b] >= self.nslot:
-            b = b2 if b == b1 else b1
-            if self._loads[b] >= self.nslot:
-                raise RuntimeError("bucket overflow")
+        b = _two_choice(self._loads, _h1(key, self.n_buckets),
+                        _h2(key, self.n_buckets), self.nslot)
         s = self._loads[b]
         self._fp[b, s] = np.int32(_fp(key) & 0x7FFFFFFF) or 1
         self._val[b, s, :len(value)] = value
@@ -589,14 +616,7 @@ class DeviceRaceTable(_Resident):
     def prep(self, keys: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """(fingerprints (NQ,) i32, candidate bucket rows (NQ, 2) i32) of
         ``keys`` — the kernel's query operands."""
-        keys = np.asarray(keys)
-        fps = np.array([(_fp(int(k)) & 0x7FFFFFFF) or 1 for k in keys],
-                       np.int32)
-        bidx = np.stack(
-            [[_h1(int(k), self.n_buckets) for k in keys],
-             [_h2(int(k), self.n_buckets) for k in keys]],
-            axis=1).astype(np.int32)
-        return fps, bidx
+        return prep_keys(keys, self.n_buckets)
 
     def tables(self) -> Tuple[np.ndarray, np.ndarray]:
         """(fp (NB, NSLOT) i32, val (NB, NSLOT, VDIM) f32) host tables."""
@@ -694,3 +714,150 @@ class ShardedDeviceRaceTable(_Resident):
                 fp_tables, val_tables = self._dev
             return race_lookup_sharded(fp_tables, val_tables, fps, bidx,
                                        sidx, impl=impl)
+
+
+#: 32-bit words of one row of the pool layout's index and key arrays: the
+#: kernel's DMAs move whole 128-lane rows
+POOL_LANES = 128
+#: RACE's slot length field counts the KV block in 64-byte units
+BLOCK_UNIT = 64
+
+
+def fp8(fps) -> np.ndarray:
+    """The 8-bit fingerprint of RACE's slot, from the 31-bit fingerprints
+    of :func:`prep_keys`: their top byte, never 0."""
+    return np.maximum((np.asarray(fps, np.int32) >> 23) & 0xFF, 1)
+
+
+class PoolRaceTable(_Resident):
+    """RACE hashing's own layout on the device: an index of 8-byte slots
+    and a pool of KV blocks, each block carrying its key.
+
+    A slot is two int32 words, ``hi = fp8 << 24 | len << 16 | ptr >> 32``
+    and ``lo = ptr & 0xFFFFFFFF``: the 8-bit fingerprint (never 0, so an
+    occupied slot never reads as the all-zero empty slot), the block's
+    length in 64-byte units and a 48-bit pointer, here a row of the pool.
+    The pool holds ``capacity`` blocks: the record, ``(capacity, 1,
+    VDIM)`` f32, and its key, the block's header, in ``keys`` aligned
+    with the rows. Keys are below 2^31 and inserted once.
+
+    The host arrays are held in the shapes the kernel reads them in
+    (``tables()``): index and keys as rows of 128 words (eight 8-slot
+    buckets a row). ``insert`` appends the block, then writes the slot,
+    as RACE writes the block before it publishes the slot, with
+    :class:`DeviceRaceTable`'s two-choice placement, and bumps
+    ``version``; ``insert_many`` places a batch exactly as that many
+    inserts would, with one bump. The device holds one copy between
+    lookups, shipped whole when ``version`` has moved.
+
+    Each ``lookup_batch`` is one :func:`repro.obs.request` counting into
+    ``stats``, with the spans of :class:`DeviceRaceTable` in the same
+    order: ``race.prep``, ``race.to_device`` (the tables, only when they
+    ship, with ``table_ships``; then the query operands) and
+    ``race.kernel`` (``variant="pool"``). ``stats.blocks`` sums on the
+    device the KV blocks each lookup fetched, so no call waits for it."""
+
+    def __init__(self, n_buckets: int = 1024, nslot: int = 8,
+                 vdim: int = 128, capacity: int = 4096):
+        words = 2 * nslot
+        if POOL_LANES % words:
+            raise ValueError(f"{nslot}-slot buckets do not tile a "
+                             f"{POOL_LANES}-word row")
+        if capacity >= 2 ** 31:
+            raise ValueError("pool rows are int32")
+        import jax.numpy as jnp
+        self.n_buckets = n_buckets
+        self.nslot = nslot
+        self.vdim = vdim
+        self.capacity = capacity
+        self._index = np.zeros((-(-n_buckets * words // POOL_LANES), 1,
+                                POOL_LANES), np.int32)
+        #: (NB, NSLOT, 2) view of the index: each slot's (hi, lo)
+        self._slots = self._index.reshape(-1)[:n_buckets * words].reshape(
+            n_buckets, nslot, 2)
+        self._keys = np.zeros((-(-capacity // POOL_LANES), 1, POOL_LANES),
+                              np.int32)
+        self._pool = np.zeros((capacity, 1, vdim), np.float32)
+        #: keys per bucket, a list: the bulk insert's loop reads it per key
+        self._loads = [0] * n_buckets
+        #: pool rows in use: the next block goes to row ``size``
+        self.size = 0
+        self.version = 0
+        self.stats = LookupStats()
+        # a device zero, so that every lookup adds two device int32s: one
+        # compiled add, made on the first lookup
+        self.stats.blocks = jnp.zeros((), jnp.int32)
+        self._len = min(-(-(4 + 4 * vdim) // BLOCK_UNIT), 0xFF)
+
+    def _hi(self, fps) -> np.ndarray:
+        """The slots' high words for keys of these 31-bit fingerprints:
+        ``fp8 << 24 | len << 16`` (the pointer's top 16 bits are 0)."""
+        hi = (fp8(fps).astype(np.uint32) << 24) | (self._len << 16)
+        return hi.view(np.int32)
+
+    def insert(self, key: int, value: np.ndarray) -> None:
+        self.insert_many(np.array([key]), np.asarray(value)[None])
+
+    def insert_many(self, keys: np.ndarray, values: np.ndarray) -> None:
+        """Insert ``keys`` with their ``values`` rows in order, each as
+        :class:`DeviceRaceTable` places it given the keys before it, with
+        one ``version`` bump. Nothing is written if a key would overflow
+        its buckets."""
+        keys = np.asarray(keys)
+        n = len(keys)
+        if n and not (0 <= keys.min() and keys.max() < 2 ** 31):
+            raise ValueError("keys are int32")
+        if self.size + n > self.capacity:
+            raise RuntimeError("pool full")
+        fps, bidx = prep_keys(keys, self.n_buckets)
+        loads = self._loads
+        bucket, slot = [], []
+        try:
+            for b1, b2 in bidx.tolist():
+                b = _two_choice(loads, b1, b2, self.nslot)
+                bucket.append(b)
+                slot.append(loads[b])
+                loads[b] += 1
+        except RuntimeError:
+            for b in bucket:
+                loads[b] -= 1
+            raise
+        rows = np.arange(self.size, self.size + n)
+        self._pool[self.size:self.size + n, 0, :np.shape(values)[-1]] = values
+        self._keys.reshape(-1)[rows] = keys
+        self._slots[bucket, slot] = np.stack([self._hi(fps), rows], axis=1)
+        self.size += n
+        self.version += 1
+
+    def prep(self, keys: np.ndarray
+             ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """(keys (NQ,) i32, 8-bit fingerprints (NQ,) i32, candidate
+        buckets (NQ, 2) i32) — the kernel's query operands."""
+        fps, bidx = prep_keys(keys, self.n_buckets)
+        return np.asarray(keys).astype(np.int32), fp8(fps), bidx
+
+    def tables(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """(index (ceil(NB * 2 * NSLOT / 128), 1, 128) i32, keys
+        (ceil(CAP / 128), 1, 128) i32, pool (CAP, 1, VDIM) f32) host
+        arrays."""
+        return self._index, self._keys, self._pool
+
+    def lookup_batch(self, keys: np.ndarray, impl: str = "pallas"):
+        import jax
+
+        from repro import obs
+        from repro.kernels.race_lookup.ops import pool_lookup
+        with obs.request(self.stats):
+            with obs.span("race.prep", keys=len(keys)):
+                operands = self.prep(keys)
+            index, bkeys, pool = self._ship_if_stale()
+            with obs.span("race.to_device",
+                          h2d_bytes=sum(a.nbytes for a in operands)):
+                operands = jax.block_until_ready(jax.device_put(operands))
+            with obs.span("race.kernel",
+                          variant="pool" if impl == "pallas" else impl):
+                values, found, blocks = pool_lookup(
+                    index, bkeys, pool, *operands, nslot=self.nslot,
+                    impl=impl)
+                self.stats.blocks = self.stats.blocks + blocks
+            return values, found

@@ -27,7 +27,8 @@ is their parent.
 The device RACE tables (``repro.kvs.race``) open one request per
 ``lookup_batch`` and count into their ``stats`` (``LookupStats``:
 ``calls``, ``keys``, ``h2d_bytes``, ``table_ships``, ``slots``,
-``padded_slots``). Their spans:
+``padded_slots``, and ``blocks``, which the pool table sums on the device
+with no span). Their spans:
 
 =================  ====================================================
 ``race.prep``      per-key hashing (and shard routing); ``keys``
@@ -40,7 +41,8 @@ The device RACE tables (``repro.kvs.race``) open one request per
                    its own, with ``table_ships``, only on a lookup that
                    follows an insert (or the first)
 ``race.kernel``    dispatch of the jitted lookup; ``variant`` is
-                   ``scalar``, ``tiled``, ``sharded`` (or ``ref``)
+                   ``scalar``, ``tiled``, ``sharded``, ``pool`` (or
+                   ``ref``)
 ``race.to_host``   sharded: the padded answers back, waiting for the
                    kernel
 ``race.scatter``   sharded: answers back to the keys' order

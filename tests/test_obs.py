@@ -13,7 +13,9 @@ import jax
 from repro import obs
 from repro.kernels.race_lookup.ops import pallas_kernel
 from repro.kernels.race_lookup.race_lookup import group_by_shard
-from repro.kvs.race import DeviceRaceTable, LookupStats, ShardedDeviceRaceTable
+from repro.kernels.race_lookup.ref import pool_lookup_ref
+from repro.kvs.race import (DeviceRaceTable, LookupStats, PoolRaceTable,
+                             ShardedDeviceRaceTable)
 
 
 @dataclasses.dataclass
@@ -181,3 +183,37 @@ def test_sharded_table_spans_and_stats(tmp_path):
     group = calls[0][3][1]
     assert group["slots"] - group["padded_slots"] == len(batches[0])
     assert group["slots"] == ns * group["qcap"]
+
+
+def test_pool_table_spans_and_stats(tmp_path):
+    nb, nslot, vdim = 64, 8, 32
+    table = PoolRaceTable(n_buckets=nb, nslot=nslot, vdim=vdim,
+                          capacity=256)
+    _filled(table, range(1, 200), vdim, 2)
+    keys = np.arange(150, 250)
+    answers, events = traced(tmp_path, lambda: [
+        table.lookup_batch(keys) for _ in range(2)])
+    for v, f in answers:
+        assert np.asarray(f)[:50].all() and not np.asarray(f)[50:].any()
+    calls = _by_call(events)
+    # the flat table's spans, in its order: the first lookup ships the
+    # index and pool in a span of its own, the second finds them resident
+    assert [[n for n, _ in c] for c in calls] == [
+        ["race.prep", "race.to_device", "race.to_device", "race.kernel"],
+        ["race.prep", "race.to_device", "race.kernel"]]
+    assert calls[0][3][1]["variant"] == calls[1][2][1]["variant"] == "pool"
+    table_bytes = sum(a.nbytes for a in table.tables())
+    queries = len(keys) * (4 + 4 + 8)
+    assert calls[0][1][1]["h2d_bytes"] == table_bytes
+    assert calls[0][1][1]["table_ships"] == 1
+    assert calls[0][2][1]["h2d_bytes"] == calls[1][1][1]["h2d_bytes"] \
+        == queries
+    assert "table_ships" not in calls[1][1][1]
+    # the blocks counted on the device: each lookup's fingerprint matches,
+    # as the reference counts them from the same tables
+    _, _, per_key = pool_lookup_ref(*table.tables(), *table.prep(keys),
+                                    nslot=nslot)
+    assert int(table.stats.blocks) == 2 * int(np.sum(per_key)) >= 2 * 50
+    assert dataclasses.replace(table.stats, blocks=0) == LookupStats(
+        calls=2, keys=2 * len(keys), h2d_bytes=table_bytes + 2 * queries,
+        table_ships=1)

@@ -1,7 +1,9 @@
 """The main path's Pallas kernels compile for a TPU v5e (Mosaic), at the
 widths chip_smoke.py drives them with: RACE lookup at vdim 256 (scalar at
 the flat table's size, tiled at one shard's size, sharded at the store's
-shard geometry) and the serverless stage gather at chunk 128.
+shard geometry), the pool-layout lookup at one memory node's 8M records
+with its index and pool in HBM, and the serverless stage gather at chunk
+128.
 
 Nothing runs: the chip is described, not attached, so these compile
 from shapes alone and check that Mosaic accepts each kernel."""
@@ -18,12 +20,16 @@ import jax.numpy as jnp
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import chip_smoke as cs  # noqa: E402
+from repro.kernels.race_lookup.pool import (  # noqa: E402
+    LANES, pool_lookup_pallas)
 from repro.kernels.race_lookup.race_lookup import (  # noqa: E402
     race_lookup_pallas, race_lookup_pallas_tiled, sharded_lookup_call)
 from repro.kernels.serverless_stage.stage import (  # noqa: E402
     CHUNK, chunk_gather_pallas)
 
 NQ = cs.BATCH
+#: the race-pool-8m-1kb deployment: 8M KV blocks, load 0.48
+POOL_RECORDS, POOL_BUCKETS = 8_000_000, 2_083_339
 SLAB_CHUNKS = 16 * (1024 // 4 // CHUNK)      # one chain slab of 16 x 1 KB
 
 
@@ -80,6 +86,16 @@ def _sharded(s):
         s((ns, qcap)), s((ns, qcap, 2)), qblock=64, interpret=False)
 
 
+def _pool(s):
+    index_rows = -(-POOL_BUCKETS * 2 * cs.NSLOT // LANES)
+    return jax.jit(functools.partial(pool_lookup_pallas, nslot=cs.NSLOT,
+                                     interpret=False)
+                   ).lower(s((index_rows, 1, LANES)),
+                           s((-(-POOL_RECORDS // LANES), 1, LANES)),
+                           s((POOL_RECORDS, 1, cs.VDIM), jnp.float32),
+                           s((NQ,)), s((NQ,)), s((NQ, 2)))
+
+
 def _stage(s):
     return jax.jit(functools.partial(chunk_gather_pallas, chunk=CHUNK,
                                      interpret=False)
@@ -87,9 +103,21 @@ def _stage(s):
                            s((SLAB_CHUNKS,)))
 
 
-@pytest.mark.parametrize("lower", [_scalar, _tiled, _sharded, _stage],
+@pytest.mark.parametrize("lower", [_scalar, _tiled, _sharded, _pool, _stage],
                          ids=["race_scalar", "race_tiled", "race_sharded",
-                              "stage_gather"])
+                              "race_pool", "stage_gather"])
 def test_kernel_compiles_for_v5e(shape_on_chip, lower):
     compiled = lower(shape_on_chip).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_the_pool_kernel_leaves_index_and_pool_where_they_are(
+        shape_on_chip):
+    """The resident index, keys and pool reach the kernel as they are:
+    no copy or relayout of them in the compiled call, only of the query
+    operands."""
+    text = _pool(shape_on_chip).compile().as_text()
+    for line in text.splitlines():
+        if " copy(" in line or "copy-start(" in line:
+            assert not any(f"%{p}" in line.split("copy", 1)[1]
+                           for p in ("index", "keys", "pool")), line
