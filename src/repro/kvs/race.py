@@ -87,6 +87,24 @@ def shard_of_key(key: int, n_shards: int) -> int:
     return ((key * 0x9E3779B1 + 0x85EBCA77) & 0xFFFFFFFF) % n_shards
 
 
+def _key_array(keys) -> np.ndarray:
+    """``keys`` as an integer array of up to 64 bits (an empty batch of
+    any dtype as int64)."""
+    keys = np.asarray(keys)
+    if keys.dtype.kind not in "iu":
+        if keys.size:
+            raise TypeError(f"keys must be integers, not {keys.dtype}")
+        keys = keys.astype(np.int64)
+    return keys
+
+
+def shard_of_keys(keys, n_shards: int) -> np.ndarray:
+    """:func:`shard_of_key` of every key of ``keys``, (NQ,) i32."""
+    u = _key_array(keys).astype(np.uint64)
+    return (((u * 0x9E3779B1 + 0x85EBCA77) & 0xFFFFFFFF)
+            % n_shards).astype(np.int32)
+
+
 def _h1(k: int, nb: int) -> int:
     return (k * 2654435761 + 7) % nb
 
@@ -102,16 +120,22 @@ def _fp(k: int) -> int:
 
 
 def prep_keys(keys, n_buckets: int) -> Tuple[np.ndarray, np.ndarray]:
-    """The device tables' host hashing of ``keys``: (31-bit fingerprints
-    (NQ,) i32, never 0, and the two candidate buckets (NQ, 2) i32)."""
-    keys = np.asarray(keys)
-    fps = np.array([(_fp(int(k)) & 0x7FFFFFFF) or 1 for k in keys],
-                   np.int32)
-    bidx = np.stack(
-        [[_h1(int(k), n_buckets) for k in keys],
-         [_h2(int(k), n_buckets) for k in keys]],
-        axis=1).astype(np.int32)
-    return fps, bidx
+    """The device tables' host hashing of ``keys``, an integer array or
+    list: (31-bit fingerprints (NQ,) i32, never 0, and the two candidate
+    buckets (NQ, 2) i32), element for element those of ``_fp``, ``_h1``
+    and ``_h2``."""
+    keys = _key_array(keys)
+    # the keys mod 2^64: a hash that keeps only the low 32 bits of its
+    # product is exact in wrapping uint64
+    u = keys.astype(np.uint64)
+    fps = np.maximum((u * 2246822519 + 1) & 0x7FFFFFFF, 1)
+    # _h1 reduces the whole product, which 64 bits cannot hold: reduce
+    # the factors first (floor mod, as Python's; exact for nb < 2^31)
+    h1 = ((keys % n_buckets).astype(np.int64) * (2654435761 % n_buckets)
+          + 7) % n_buckets
+    h2 = (((u * 0x85EBCA6B + 0x9E3779B9) & 0xFFFFFFFF) >> 8) % n_buckets
+    return (fps.astype(np.int32),
+            np.stack([h1.astype(np.int32), h2.astype(np.int32)], axis=1))
 
 
 def _two_choice(loads, b1: int, b2: int, nslot: int) -> int:
@@ -694,9 +718,7 @@ class ShardedDeviceRaceTable(_Resident):
         """(fingerprints, intra-shard bucket rows (NQ, 2), owning shard
         (NQ,)) of ``keys``; every shard shares one bucket geometry."""
         fps, bidx = self.shards[0].prep(keys)
-        sidx = np.array([self.shard_of(int(k)) for k in np.asarray(keys)],
-                        np.int32)
-        return fps, bidx, sidx
+        return fps, bidx, shard_of_keys(keys, self.n_shards)
 
     def tables(self) -> Tuple[np.ndarray, np.ndarray]:
         """The per-shard host tables, stacked (no copy): (NS, NB, NSLOT)

@@ -31,7 +31,7 @@ The device RACE tables (``repro.kvs.race``) open one request per
 with no span). Their spans:
 
 =================  ====================================================
-``race.prep``      per-key hashing (and shard routing); ``keys``
+``race.prep``      host hashing (and shard routing); ``keys``
 ``race.stack``     sharded: the resident stacked tables handed to the
                    kernel path (no copy)
 ``race.group``     sharded: queries grouped and padded per shard;
