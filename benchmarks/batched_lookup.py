@@ -1,16 +1,13 @@
-"""Batched data-plane benchmark: tiled vs per-query RACE-lookup kernel
-(batch size x value dim sweep) plus the simulated-fabric doorbell-batching
-paths (qpush_batch vs per-WR qpush, lookup_many vs per-key lookup).
+"""Batched data-plane benchmark on the simulated fabric: the
+doorbell-batching paths (qpush_batch vs per-WR qpush, lookup_many vs
+per-key lookup) and the notify-driven single-op wait.
 
 Emits ``BENCH_batched_lookup.json`` (repo root by default):
 
     PYTHONPATH=src python -m benchmarks.batched_lookup
     PYTHONPATH=src python -m benchmarks.batched_lookup --smoke   # tiny
 
-Kernel timings are interpret-mode wall clock (the Pallas bodies execute as
-compiled XLA on CPU), so "throughput" here measures the grid/tiling
-structure — one step per QBLOCK queries vs one per query — not TPU cycles;
-the >= 5x acceptance gate at batch >= 128 is on that simulated number.
+Times are on the simulated microsecond clock, not wall clock.
 """
 
 from __future__ import annotations
@@ -19,8 +16,7 @@ import argparse
 import json
 import os
 import sys
-import time
-from typing import Dict, List
+from typing import Dict
 
 import numpy as np
 
@@ -28,81 +24,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 DEFAULT_OUT = os.path.join(REPO_ROOT, "BENCH_batched_lookup.json")
-
-
-def _time_call(fn, repeats: int = 3) -> float:
-    """Best-of wall time in us (after a warmup call). Best-of (not mean)
-    because these are wall-clock measurements on a shared host: transient
-    CPU contention only ever adds time, so the minimum is the least-noisy
-    estimate of the kernel's actual cost."""
-    fn()
-    best = float("inf")
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        fn()
-        best = min(best, time.perf_counter() - t0)
-    return best * 1e6
-
-
-def _time_pair(fn_a, fn_b, repeats: int = 5):
-    """Interleaved best-of timing of two impls (A, B, A, B, ...) so a load
-    spike on a shared host inflates both sides instead of biasing the
-    ratio; returns (best_a_us, best_b_us)."""
-    fn_a(), fn_b()                                   # warmup both
-    best_a = best_b = float("inf")
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        fn_a()
-        best_a = min(best_a, time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        fn_b()
-        best_b = min(best_b, time.perf_counter() - t0)
-    return best_a * 1e6, best_b * 1e6
-
-
-# ------------------------------------------------------------ kernel sweep
-def bench_kernel_sweep(batches, vdims, *, nb=256, nslot=8,
-                       qblock=64, repeats=5) -> List[Dict]:
-    from repro.kernels.race_lookup.ops import race_lookup
-    from repro.kernels.race_lookup.ref import make_table
-
-    rows: List[Dict] = []
-    for vdim in vdims:
-        rng = np.random.RandomState(vdim)
-        nkeys = min(nb * nslot // 3, 500)
-        keys = np.arange(1, nkeys + 1)
-        vals = rng.randn(nkeys, vdim).astype(np.float32)
-        fp, vt, prep = make_table(nb, nslot, vdim, keys, vals)
-        for batch in batches:
-            qkeys = rng.randint(1, 2 * nkeys, batch)
-            fps, bidx = prep(qkeys)
-
-            def run(impl):
-                v, f = race_lookup(fp, vt, fps, bidx, impl=impl,
-                                   qblock=qblock)
-                v.block_until_ready()
-                return v, f
-
-            # cross-check the two kernels once per config
-            v_t, f_t = run("pallas")
-            v_s, f_s = run("pallas_scalar")
-            np.testing.assert_array_equal(np.array(f_t), np.array(f_s))
-            np.testing.assert_allclose(np.array(v_t), np.array(v_s),
-                                       atol=1e-6)
-
-            scalar_us, tiled_us = _time_pair(
-                lambda: run("pallas_scalar"), lambda: run("pallas"),
-                repeats)
-            rows.append({
-                "batch": int(batch), "vdim": int(vdim),
-                "qblock": int(min(qblock, batch)),
-                "scalar_us": round(scalar_us, 1),
-                "tiled_us": round(tiled_us, 1),
-                "scalar_qps": round(batch / scalar_us * 1e6),
-                "tiled_qps": round(batch / tiled_us * 1e6),
-                "speedup": round(scalar_us / tiled_us, 2),
-            })
-    return rows
 
 
 # ------------------------------------------------------- fabric doorbells
@@ -327,21 +248,15 @@ def bench_kv_batching(n_keys=48) -> Dict:
 # ------------------------------------------------------------------- main
 def run_suite(smoke: bool = False) -> Dict:
     if smoke:
-        # best-of-3 (interleaved): a single wall-clock sample of a
-        # hundreds-of-us kernel is one scheduler hiccup away from a false
-        # CI failure; three samples cost < 1s extra
-        kernel = bench_kernel_sweep([16, 64], [64], nb=64, qblock=8,
-                                    repeats=3)
         # n_wrs=128: the session-overhead gate is defined at batch >= 128
         fabric = bench_fabric_batching(n_wrs=128, signal_interval=8)
         kv = bench_kv_batching(n_keys=8)
         notify = bench_notify_single_op(n_ops=16)
     else:
-        kernel = bench_kernel_sweep([8, 32, 128, 512], [64, 128, 256])
         fabric = bench_fabric_batching()
         kv = bench_kv_batching()
         notify = bench_notify_single_op()
-    return {"kernel_sweep": kernel, "fabric_qpush_batch": fabric,
+    return {"fabric_qpush_batch": fabric,
             "kv_lookup_many": kv, "notify_single_op": notify}
 
 
@@ -360,10 +275,6 @@ def main() -> None:
     results = run_suite(smoke=args.smoke)
     with open(args.out, "w") as f:
         json.dump(results, f, indent=2)
-    for row in results["kernel_sweep"]:
-        print(f"kernel batch={row['batch']:4d} vdim={row['vdim']:4d} "
-              f"scalar={row['scalar_us']:.0f}us tiled={row['tiled_us']:.0f}"
-              f"us speedup={row['speedup']:.1f}x")
     fb = results["fabric_qpush_batch"]
     print(f"fabric qpush_batch n={fb['n_wrs']} "
           f"per-op={fb['per_op_us_per_wr']}us/wr "
@@ -379,10 +290,6 @@ def main() -> None:
           f"idle_polls read={ns['read_idle_polls']} "
           f"call={ns['call_idle_polls']}")
     print(f"wrote {args.out}")
-    # acceptance gate: tiled >= 5x at batch >= 128 (full run only)
-    big = [r for r in results["kernel_sweep"] if r["batch"] >= 128]
-    if big and min(r["speedup"] for r in big) < 5.0:
-        raise SystemExit("tiled kernel under 5x at batch >= 128")
 
 
 if __name__ == "__main__":

@@ -572,11 +572,10 @@ def bench_fig14() -> List[Row]:
 # ================================================ batched data plane
 def bench_batched() -> List[Row]:
     """Throughput rows for the batch-first fast path: qpush_batch doorbell
-    batching, batched KV lookups, and the tiled multi-query lookup kernel
-    (vs their per-op counterparts). Full sweep + JSON artifact:
+    batching and batched KV lookups (vs their per-op counterparts). Full
+    sweep + JSON artifact:
     ``python -m benchmarks.batched_lookup``."""
     from benchmarks.batched_lookup import (bench_fabric_batching,
-                                           bench_kernel_sweep,
                                            bench_kv_batching)
 
     rows: List[Row] = []
@@ -589,11 +588,6 @@ def bench_batched() -> List[Row]:
                  kv["batched_us_per_key"],
                  f"per-key={kv['per_op_us_per_key']}us/key "
                  f"speedup={kv['speedup']}x"))
-    for r in bench_kernel_sweep([128], [128], repeats=2):
-        rows.append((f"batched/kernel_tiled_b{r['batch']}_v{r['vdim']}",
-                     r["tiled_us"],
-                     f"scalar={r['scalar_us']}us tput={r['tiled_qps']}q/s "
-                     f"speedup={r['speedup']}x (interpret)"))
     return rows
 
 

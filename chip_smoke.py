@@ -2,17 +2,12 @@
 user calls, at a deployment's size, and check each against its plain
 reference. Everything runs in this one process, on one JAX client.
 
-    python3 chip_smoke.py                # one TPU: store, chain, serve
+    python3 chip_smoke.py                # one TPU: chain, serve
     python3 chip_smoke.py --four-chips   # four TPUs: elastic scale events
 
-Phases on one chip:
+Phases on one chip (the device RACE tables are checked against their
+records in every run of the benchmark, ``bench/run.py``):
 
-* store — a ``ShardedDeviceRaceTable`` holding 1,000,000 YCSB-sized records
-  (1 KB: 10 fields x 100 B = ``vdim`` 256 float32) in ~2M slots, answering
-  batches of 4096 lookups (present, absent, repeated keys); then one
-  unsharded ``DeviceRaceTable`` of the same size (scalar kernel) and one
-  under the VMEM budget (tiled kernel). Every answer must be bit-equal to
-  ``race_lookup_ref`` on the same tables and to the inserted record.
 * chain — a 3-stage A->B->C serverless chain through ``ChainRunner``
   (k = 32 and 128, 1 KB payloads, 16 per slab), packed and unpacked by the
   stage kernel: outputs byte-equal to ``expected_outputs`` and at most
@@ -44,18 +39,6 @@ import numpy as np
 
 sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 
-# store geometry: 1M records in 251 shards x 1021 buckets x 8 slots
-# (2,050,168 slots, load 0.49); prime counts keep the multiplicative
-# bucket and shard hashes from sharing factors with the geometry
-N_RECORDS = 1_000_000
-VDIM = 256                        # 1 KB record = 256 float32
-NSLOT = 8
-N_SHARDS = 251
-SHARD_BUCKETS = 1021              # one shard: 8.4 MB values, fits VMEM
-FLAT_BUCKETS = 262_139            # same slot count, one table -> scalar
-SMALL_RECORDS = 4_000             # one shard's worth -> tiled kernel
-BATCH = 4096
-
 #: max |loss(4 workers) - loss(1 worker)| / loss(1 worker): the same bf16
 #: program on a quarter of the batch per device; only reduction order and
 #: per-device tiling differ
@@ -73,120 +56,6 @@ class SmokeFailure(RuntimeError):
 def check(cond: bool, what: str) -> None:
     if not cond:
         raise SmokeFailure(what)
-
-
-# ------------------------------------------------------------------ store
-def _bits(a) -> np.ndarray:
-    return np.ascontiguousarray(np.asarray(a, np.float32)).view(np.uint32)
-
-
-def _lookup_checks(table, label, kernel, ref_fn, keys, values, rng):
-    """Answer the batches through ``table.lookup_batch`` and count every
-    answer that differs from the reference or from the inserted record."""
-    n = len(keys)
-    absent = rng.integers(2 ** 30, 2 ** 31 - 1, BATCH)
-    present = rng.choice(n, BATCH, replace=n < BATCH)
-    rep = rng.choice(n, BATCH // 4, replace=False)
-    mixed = np.concatenate([np.repeat(rep, 3), -1 - np.arange(BATCH // 4)])
-    rng.shuffle(mixed)
-    batches = {"present": present, "absent": -1 - np.arange(BATCH),
-               "repeated": mixed}
-    for name, idx in batches.items():
-        hit = idx >= 0
-        qkeys = np.where(hit, keys[np.where(hit, idx, 0)],
-                         absent[np.where(hit, 0, -1 - idx)])
-        t0 = time.perf_counter()
-        v, f = table.lookup_batch(qkeys)
-        v, f = np.asarray(v), np.asarray(f)
-        first_s = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        np.asarray(table.lookup_batch(qkeys)[0])
-        warm_s = time.perf_counter() - t0
-        rv, rf = ref_fn(qkeys)
-        rv, rf = np.asarray(rv), np.asarray(rf)
-        vs_ref = int(np.sum((f != rf) | np.any(_bits(v) != _bits(rv), 1)))
-        want = np.where(hit[:, None], values[np.where(hit, idx, 0)], 0)
-        vs_truth = int(np.sum((f != hit) | np.any(_bits(v) != _bits(want),
-                                                    1)))
-        emit(phase="store", table=label, kernel=kernel, batch=name,
-             lookups=len(qkeys), distinct=int(len(np.unique(qkeys))),
-             found=int(f.sum()), mismatches_vs_ref=vs_ref,
-             mismatches_vs_inserted=vs_truth,
-             first_call_s=round(first_s, 4), warm_call_s=round(warm_s, 4))
-        check(vs_ref == 0 and vs_truth == 0,
-              f"store/{label}/{name}: {vs_ref} answers differ from "
-              f"race_lookup_ref, {vs_truth} from the inserted records")
-
-
-def _load(table, keys, values) -> float:
-    t0 = time.perf_counter()
-    for k, v in zip(keys.tolist(), values):
-        table.insert(k, v)
-    return time.perf_counter() - t0
-
-
-def phase_store(seed: int) -> None:
-    import jax
-    from repro.kernels.race_lookup.ops import pallas_kernel
-    from repro.kernels.race_lookup.ref import race_lookup_ref
-    from repro.kvs.race import DeviceRaceTable, ShardedDeviceRaceTable
-
-    emit(phase="store", reduced=[
-        f"{N_RECORDS:,} records (~2 GB of values, an eighth of one v5e's "
-        "HBM); uniform keys, not YCSB's zipfian",
-        f"3 batches of {BATCH} lookups per table, a check and not a timed "
-        "run"])
-    rng = np.random.default_rng(seed)
-    keys = rng.choice(2 ** 30 - 1, N_RECORDS, replace=False) + 1
-    values = rng.standard_normal((N_RECORDS, VDIM), dtype=np.float32)
-    ref = jax.jit(race_lookup_ref)
-
-    # sharded: the dkv shard map, one shard VMEM-resident per grid step
-    table = ShardedDeviceRaceTable(n_shards=N_SHARDS,
-                                   n_buckets=SHARD_BUCKETS, nslot=NSLOT,
-                                   vdim=VDIM)
-    load_s = _load(table, keys, values)
-    fp, val = table.tables()
-    kernel = pallas_kernel(fp.shape[1:], val.shape[1:])
-    check(kernel == "tiled", f"a shard routes to the {kernel} kernel")
-    emit(phase="store", table="sharded", records=N_RECORDS,
-         shards=N_SHARDS, buckets_per_shard=SHARD_BUCKETS, nslot=NSLOT,
-         vdim=VDIM, value_table_bytes=int(val.nbytes),
-         load=round(N_RECORDS / (val.size // VDIM), 4),
-         insert_s=round(load_s, 2))
-    fp_d = jax.device_put(fp.reshape(-1, NSLOT))
-    val_d = jax.device_put(val.reshape(-1, NSLOT, VDIM))
-    del fp, val
-
-    def ref_sharded(qkeys):
-        fps, bidx, sidx = table.prep(qkeys)
-        return ref(fp_d, val_d, fps, bidx + sidx[:, None] * SHARD_BUCKETS)
-
-    _lookup_checks(table, "sharded", "sharded", ref_sharded, keys, values,
-                   rng)
-    del table, fp_d, val_d
-
-    # one unsharded table per kernel: same size (scalar), one shard's
-    # size (tiled)
-    for label, nb, n in (("flat", FLAT_BUCKETS, N_RECORDS),
-                         ("small", SHARD_BUCKETS, SMALL_RECORDS)):
-        table = DeviceRaceTable(n_buckets=nb, nslot=NSLOT, vdim=VDIM)
-        load_s = _load(table, keys[:n], values[:n])
-        fp, val = table.tables()
-        kernel = pallas_kernel(fp.shape, val.shape)
-        check(kernel == ("scalar" if label == "flat" else "tiled"),
-              f"store/{label} routes to the {kernel} kernel")
-        emit(phase="store", table=label, records=n, buckets=nb,
-             nslot=NSLOT, vdim=VDIM, value_table_bytes=int(val.nbytes),
-             insert_s=round(load_s, 2))
-        fp_d, val_d = jax.device_put(fp), jax.device_put(val)
-
-        def ref_flat(qkeys, table=table, fp_d=fp_d, val_d=val_d):
-            return ref(fp_d, val_d, *table.prep(qkeys))
-
-        _lookup_checks(table, label, kernel, ref_flat, keys[:n],
-                       values[:n], rng)
-        del table, fp_d, val_d
 
 
 # ------------------------------------------------------------------ chain
@@ -347,7 +216,7 @@ def main() -> int:
     emit(phase="setup", compile_cache=enable_compile_cache(),
          device_kind=dev.device_kind, devices=len(devices))
     phases = ([phase_elastic] if args.four_chips
-              else [phase_store, phase_chain, phase_serve])
+              else [phase_chain, phase_serve])
     for phase in phases:
         t0 = time.perf_counter()
         phase(args.seed)

@@ -6,43 +6,31 @@ and the lookup is a gather: for each query, fetch its TWO candidate buckets
 (RACE extendible hashing), compare fingerprints against all slots, and
 select the matching value row — one fused kernel, no host round-trip.
 
-Three kernels live here:
+Two kernels live here, one per inline table layout:
 
-``race_lookup_pallas_tiled`` (the fast path)
-    ``QBLOCK`` queries per grid step. The bucket rows ride the scalar-
-    prefetch lane (SMEM); the tile's 2*QBLOCK candidate buckets are copied
-    row by row out of the VMEM-resident table into a VMEM scratch tile,
-    the fingerprint compare runs vectorized over the whole ``(QBLOCK,
-    NSLOT)`` tile of each bucket on the VPU, and the value select is a
-    one-hot ``(QBLOCK, QBLOCK*2*NSLOT) @ (QBLOCK*2*NSLOT, VDIM)``
-    contraction so the MXU engages (the per-query select never fills a
-    128x128 tile). The contraction runs at HIGHEST precision: a one-hot
-    row then returns the stored f32 value bit-exact. Ragged tails are
-    auto-padded with null queries (fingerprint 0 matches nothing) and
-    sliced off after the call.
-
-    Tiling choice: ``QBLOCK`` defaults to 64 — with the RACE default
-    ``NSLOT=8`` that makes the one-hot contraction a (64, 1024) @ (1024,
-    VDIM) matmul, comfortably MXU-shaped for VDIM >= 128 while keeping the
-    gathered value tile (QBLOCK*2*NSLOT*VDIM*4 B = 1 MB at VDIM=256) well
-    inside VMEM. Both tables are kept VMEM-resident across grid steps
-    (constant index map), which bounds the table to
-    ``TILED_VMEM_BUDGET_BYTES``; shard the table above that.
-
-``race_lookup_pallas`` (scalar fallback)
+``race_lookup_pallas`` (the flat table)
     One query per grid step. Its scalar-prefetch BlockSpecs DMA exactly
     the two candidate buckets per step, so it has no VMEM table-size
     bound; the query fingerprint is read from the scalar-prefetch lane
     too.
 
 ``race_lookup_pallas_sharded`` (the dkv shard map)
-    The sharded sibling of the tiled kernel: per-shard tables stacked as
-    ``(NS, NB, NSLOT)`` / ``(NS, NB, NSLOT, VDIM)`` and a **per-shard
-    index map** — the grid gains a leading shard dimension and each grid
-    step's BlockSpec selects ONLY that shard's table, so VMEM holds one
-    shard at a time instead of pinning the whole multi-shard array with a
-    constant index map. Queries are grouped per shard host-side
-    (:func:`group_by_shard`), padded to the tile size, run through
+    Per-shard tables stacked as ``(NS, NB, NSLOT)`` / ``(NS, NB, NSLOT,
+    VDIM)`` and a **per-shard index map**: the grid's leading dimension
+    is the shard and each grid step's BlockSpec selects ONLY that shard's
+    table, so VMEM holds one shard at a time (at most
+    ``SHARD_VMEM_BUDGET_BYTES``). ``QBLOCK`` queries per grid step: the
+    bucket rows ride the scalar-prefetch lane (SMEM); the tile's 2*QBLOCK
+    candidate buckets are copied row by row out of the VMEM-resident
+    shard into a VMEM scratch tile, the fingerprint compare runs
+    vectorized over the whole ``(QBLOCK, NSLOT)`` tile on the VPU, and
+    the value select is a one-hot ``(QBLOCK, QBLOCK*2*NSLOT) @
+    (QBLOCK*2*NSLOT, VDIM)`` contraction so the MXU engages (QBLOCK 64
+    with 8-slot buckets makes it a (64, 1024) @ (1024, VDIM) matmul). The
+    contraction runs at HIGHEST precision: a one-hot row then returns the
+    stored f32 value bit-exact. Queries are grouped per shard host-side
+    (:func:`group_by_shard`), padded to the tile size with null queries
+    (fingerprint 0 matches nothing), run through
     :func:`sharded_lookup_call` (a pure function of shapes) and scattered
     back to input order. The minor grid dimension iterates tiles within a
     shard, so consecutive steps reuse the resident shard block.
@@ -65,14 +53,13 @@ from jax.experimental.pallas import tpu as pltpu
 from repro import obs
 from repro.kernels import interpret_mode
 
-#: padded VMEM bytes (see :func:`table_vmem_bytes`) up to which a table
-#: (or one shard) is pinned VMEM-resident for the tiled/sharded kernel;
-#: above it ``race_lookup`` routes to the scalar kernel's per-bucket DMA
-TILED_VMEM_BUDGET_BYTES = 16 * 1024 * 1024
-#: scoped VMEM the tiled and sharded kernels request: two pipeline buffers
-#: of a budget-sized table (32 MiB) plus the gathered value tile, one-hot
-#: and output blocks (< 8 MiB at VDIM <= 1024). Above v5e's 16 MiB
-#: default scoped limit, well under its 128 MiB of VMEM.
+#: padded VMEM bytes (see :func:`table_vmem_bytes`) of the largest shard
+#: the sharded kernel pins VMEM-resident for a grid step
+SHARD_VMEM_BUDGET_BYTES = 16 * 1024 * 1024
+#: scoped VMEM the sharded kernel requests: two pipeline buffers of a
+#: budget-sized shard (32 MiB) plus the gathered value tile, one-hot and
+#: output blocks (< 8 MiB at VDIM <= 1024). Above v5e's 16 MiB default
+#: scoped limit, well under its 128 MiB of VMEM.
 VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
@@ -81,8 +68,8 @@ def _rup(x: int, m: int) -> int:
 
 
 def table_vmem_bytes(fp_shape, val_shape) -> int:
-    """VMEM bytes of one 32-bit (fp, val) table pair once the last two
-    dims of each are padded to the (8, 128) tile — the NSLOT-wide
+    """VMEM bytes of one shard's 32-bit (fp, val) table pair once the last
+    two dims of each are padded to the (8, 128) tile — the NSLOT-wide
     fingerprint rows take a full 128-lane row each."""
     nb, nslot = fp_shape[-2:]
     vdim = val_shape[-1]
@@ -90,7 +77,7 @@ def table_vmem_bytes(fp_shape, val_shape) -> int:
                 + nb * _rup(nslot, 8) * _rup(vdim, 128))
 
 
-# ------------------------------------------------------- scalar fallback
+# ------------------------------------------------------------ flat table
 def _first_hit(fps, q, slot, nslot):
     """Per row, the candidate index of the first slot whose fingerprint
     matches ``q`` (2*NSLOT when none does). ``slot`` numbers the columns
@@ -125,6 +112,9 @@ def race_lookup_pallas(fp_table, val_table, queries, bucket_idx,
     nb, nslot = fp_table.shape
     vdim = val_table.shape[-1]
     nq = queries.shape[0]
+    if nq == 0:
+        return (jnp.zeros((0, vdim), val_table.dtype),
+                jnp.zeros((0,), jnp.int32))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -157,30 +147,30 @@ def race_lookup_pallas(fp_table, val_table, queries, bucket_idx,
     return values.reshape(nq, vdim), found.reshape(nq)
 
 
-# -------------------------------------------------------- tiled fast path
+# --------------------------------------------------------- sharded table
 def _gather_tile(rows_ref, base, fp_ref, val_ref, fp1_sc, fp2_sc, val_sc,
-                 *, qblock, nslot, lead=()):
+                 *, qblock, nslot):
     """Copy the tile's 2*QBLOCK candidate buckets out of the resident
-    table: bucket-1/bucket-2 fingerprint rows into ``fp1_sc``/``fp2_sc``
+    shard: bucket-1/bucket-2 fingerprint rows into ``fp1_sc``/``fp2_sc``
     (QBLOCK, NSLOT), and the value rows, per query contiguous (q0b0,
     q0b1, q1b0, ...), into ``val_sc`` (QBLOCK*2*NSLOT, VDIM). The bucket
-    rows are read from SMEM at ``rows_ref[base + 2*i + b]``; ``lead``
-    indexes the block's leading singleton axes (the shard axis)."""
+    rows are read from SMEM at ``rows_ref[base + 2*i + b]``; the block's
+    leading singleton shard axis is indexed away."""
     def body(i, carry):
         r1 = rows_ref[base + 2 * i]
         r2 = rows_ref[base + 2 * i + 1]
-        fp1_sc[pl.ds(i, 1), :] = fp_ref[lead + (pl.ds(r1, 1), slice(None))]
-        fp2_sc[pl.ds(i, 1), :] = fp_ref[lead + (pl.ds(r2, 1), slice(None))]
+        fp1_sc[pl.ds(i, 1), :] = fp_ref[0, pl.ds(r1, 1), :]
+        fp2_sc[pl.ds(i, 1), :] = fp_ref[0, pl.ds(r2, 1), :]
         off = pl.multiple_of(i * 2 * nslot, 2 * nslot)
-        val_sc[pl.ds(off, nslot), :] = val_ref[lead + (r1,)]
-        val_sc[pl.ds(off + nslot, nslot), :] = val_ref[lead + (r2,)]
+        val_sc[pl.ds(off, nslot), :] = val_ref[0, r1]
+        val_sc[pl.ds(off + nslot, nslot), :] = val_ref[0, r2]
         return carry
 
     jax.lax.fori_loop(0, qblock, body, 0)
 
 
 def _tile_select(q, fp1, fp2, vals, *, qblock, nslot):
-    """Shared tile body of the tiled and sharded kernels.
+    """The sharded kernel's tile body, on one shard.
 
     Compare fingerprints across the whole tile (VPU), then select each
     query's first-hit value row with ONE flat one-hot contraction
@@ -212,79 +202,16 @@ def _tile_scratch(qblock, nslot, vdim, dtype):
             pltpu.VMEM((qblock * 2 * nslot, vdim), dtype)]
 
 
-def _lookup_kernel_tiled(rows_ref, q_ref, fp_ref, val_ref, out_ref,
-                         found_ref, fp1_sc, fp2_sc, val_sc, *, qblock,
-                         nslot):
-    """QBLOCK queries per grid step against the VMEM-resident table."""
-    _gather_tile(rows_ref, pl.program_id(0) * 2 * qblock, fp_ref, val_ref,
-                 fp1_sc, fp2_sc, val_sc, qblock=qblock, nslot=nslot)
-    out, found = _tile_select(q_ref[...], fp1_sc[...], fp2_sc[...],
-                              val_sc[...], qblock=qblock, nslot=nslot)
-    out_ref[...] = out
-    found_ref[...] = found.astype(jnp.int32)
-
-
-def race_lookup_pallas_tiled(fp_table, val_table, queries, bucket_idx,
-                             *, qblock: int = 64,
-                             interpret: bool | None = None):
-    """Tiled multi-query lookup; same contract as ``race_lookup_pallas``.
-
-    Pads NQ up to a multiple of ``qblock`` with null queries (fingerprint
-    0 never matches an occupied slot, bucket 0 is a valid row) and slices
-    the pad off the outputs.
-    """
-    nb, nslot = fp_table.shape
-    vdim = val_table.shape[-1]
-    nq = queries.shape[0]
-    if nq == 0:
-        return (jnp.zeros((0, vdim), val_table.dtype),
-                jnp.zeros((0,), jnp.int32))
-    qblock = min(qblock, _rup(nq, 8))
-    pad = (-nq) % qblock
-    if pad:
-        queries = jnp.pad(queries, (0, pad))
-        bucket_idx = jnp.pad(bucket_idx, ((0, pad), (0, 0)))
-    nq_pad = nq + pad
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nq_pad // qblock,),
-        in_specs=[
-            pl.BlockSpec((qblock, 1), lambda i, rows: (i, 0)),  # query fps
-            pl.BlockSpec((nb, nslot), lambda i, rows: (0, 0)),  # fp table
-            pl.BlockSpec((nb, nslot, vdim), lambda i, rows: (0, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((qblock, vdim), lambda i, rows: (i, 0)),
-            pl.BlockSpec((qblock, 1), lambda i, rows: (i, 0)),
-        ],
-        scratch_shapes=_tile_scratch(qblock, nslot, vdim, val_table.dtype),
-    )
-    values, found = pl.pallas_call(
-        functools.partial(_lookup_kernel_tiled, qblock=qblock, nslot=nslot),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((nq_pad, vdim), val_table.dtype),
-            jax.ShapeDtypeStruct((nq_pad, 1), jnp.int32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        interpret=interpret_mode() if interpret is None else interpret,
-    )(bucket_idx.reshape(2 * nq_pad), queries.reshape(nq_pad, 1),
-      fp_table, val_table)
-    return values[:nq], found[:nq, 0]
-
-
-# ---------------------------------------------------- sharded fast path
 def _lookup_kernel_sharded(rows_ref, q_ref, fp_ref, val_ref, out_ref,
                            found_ref, fp1_sc, fp2_sc, val_sc, *, qblock,
                            nslot):
     """One (shard, tile) pair per grid step: the BlockSpec index map has
-    already selected shard ``s``'s table, so the body is exactly the
-    tiled kernel's — with a leading singleton shard axis squeezed off."""
+    already selected shard ``s``'s table, so the body gathers and selects
+    within that one shard, its leading singleton shard axis squeezed
+    off."""
     tile = pl.program_id(0) * pl.num_programs(1) + pl.program_id(1)
     _gather_tile(rows_ref, tile * 2 * qblock, fp_ref, val_ref, fp1_sc,
-                 fp2_sc, val_sc, qblock=qblock, nslot=nslot, lead=(0,))
+                 fp2_sc, val_sc, qblock=qblock, nslot=nslot)
     out, found = _tile_select(q_ref[0], fp1_sc[...], fp2_sc[...],
                               val_sc[...], qblock=qblock, nslot=nslot)
     out_ref[0] = out

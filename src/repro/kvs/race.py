@@ -606,8 +606,8 @@ class DeviceRaceTable(_Resident):
     ``stats``, with the spans ``race.prep`` (hashing), ``race.to_device``
     (the table, only when it ships, with ``table_ships``),
     ``race.to_device`` (the query operands, until they are on the
-    device) and ``race.kernel`` (dispatch of the jitted lookup; the
-    answers stay on the device).
+    device) and ``race.kernel`` (dispatch of the jitted lookup,
+    ``variant="scalar"``; the answers stay on the device).
 
     ``fp`` and ``val`` are host arrays to hold the table in (a sharded
     table's shards are views into its stacked arrays); fresh zeros if
@@ -650,7 +650,7 @@ class DeviceRaceTable(_Resident):
         import jax
 
         from repro import obs
-        from repro.kernels.race_lookup.ops import pallas_kernel, race_lookup
+        from repro.kernels.race_lookup.ops import race_lookup
         with obs.request(self.stats):
             with obs.span("race.prep", keys=len(keys)):
                 fps, bidx = self.prep(keys)
@@ -659,9 +659,8 @@ class DeviceRaceTable(_Resident):
                           h2d_bytes=fps.nbytes + bidx.nbytes):
                 fps, bidx = jax.block_until_ready(
                     jax.device_put((fps, bidx)))
-            variant = (pallas_kernel(self._fp.shape, self._val.shape)
-                       if impl == "pallas" else impl.removeprefix("pallas_"))
-            with obs.span("race.kernel", variant=variant):
+            with obs.span("race.kernel",
+                          variant="scalar" if impl == "pallas" else impl):
                 return race_lookup(fp_table, val_table, fps, bidx,
                                    impl=impl)
 
@@ -671,8 +670,9 @@ class ShardedDeviceRaceTable(_Resident):
     dkv shard map. Per-shard tables share one geometry and batched
     lookups run through the SHARDED Pallas kernel
     (``race_lookup_sharded``): the grid gains a shard dimension and only
-    ONE shard's table is resident per grid step, instead of the whole
-    multi-shard array pinned VMEM-resident at once.
+    ONE shard's table is resident per grid step, so one shard must fit
+    the kernel's VMEM budget (``SHARD_VMEM_BUDGET_BYTES``); a larger shard
+    geometry raises ``ValueError``.
 
     Residency: the host tables are held stacked from construction, (NS,
     NB, NSLOT) and (NS, NB, NSLOT, VDIM), and each shard's tables are
@@ -691,6 +691,15 @@ class ShardedDeviceRaceTable(_Resident):
 
     def __init__(self, n_shards: int = 4, n_buckets: int = 256,
                  nslot: int = 8, vdim: int = 128):
+        from repro.kernels.race_lookup.race_lookup import (
+            SHARD_VMEM_BUDGET_BYTES, table_vmem_bytes)
+        shard_bytes = table_vmem_bytes((n_buckets, nslot),
+                                       (n_buckets, nslot, vdim))
+        if shard_bytes > SHARD_VMEM_BUDGET_BYTES:
+            raise ValueError(
+                f"a shard of {n_buckets} x {nslot} x {vdim} takes "
+                f"{shard_bytes} B of VMEM, over the sharded kernel's "
+                f"{SHARD_VMEM_BUDGET_BYTES} B")
         self.n_shards = n_shards
         self.n_buckets = n_buckets
         self.nslot = nslot

@@ -41,8 +41,7 @@ with no span). Their spans:
                    its own, with ``table_ships``, only on a lookup that
                    follows an insert (or the first)
 ``race.kernel``    dispatch of the jitted lookup; ``variant`` is
-                   ``scalar``, ``tiled``, ``sharded``, ``pool`` (or
-                   ``ref``)
+                   ``scalar``, ``sharded``, ``pool`` (or ``ref``)
 ``race.to_host``   sharded: the padded answers back, waiting for the
                    kernel
 ``race.scatter``   sharded: answers back to the keys' order

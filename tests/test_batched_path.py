@@ -1,5 +1,5 @@
 """Batched data-plane invariants (qpush_batch / qpop_batch / get_many /
-lookup_many / tiled race-lookup kernel) plus regression tests for the
+lookup_many / flat-table race-lookup kernel) plus regression tests for the
 pool.decay and QP.reset_from_error fixes."""
 
 import math
@@ -525,36 +525,20 @@ def test_reset_from_error_with_wr_in_flight():
     assert qa._done_buffer == {}
 
 
-# ========================================================== tiled kernel
-@pytest.mark.parametrize("nq,qblock", [(1, 8), (7, 8), (64, 64),
-                                       (65, 64), (130, 32)])
-def test_tiled_kernel_ragged_tails_match_ref(nq, qblock):
+# ================================================ flat-table lookup kernel
+@pytest.mark.parametrize("nq", [1, 7, 64, 65, 130])
+def test_flat_kernel_ragged_batches_match_ref(nq):
     from repro.kernels.race_lookup.ops import race_lookup
     from repro.kernels.race_lookup.ref import make_table, race_lookup_ref
 
-    rng = np.random.RandomState(nq * 31 + qblock)
+    rng = np.random.RandomState(nq * 31)
     nkeys, vdim = 150, 64
     keys = np.arange(1, nkeys + 1)
     vals = rng.randn(nkeys, vdim).astype(np.float32)
     fp, vt, prep = make_table(128, 8, vdim, keys, vals)
     qkeys = rng.randint(1, 2 * nkeys, nq)          # mix of hits and misses
     fps, bidx = prep(qkeys)
-    v_t, f_t = race_lookup(fp, vt, fps, bidx, impl="pallas", qblock=qblock)
+    v_k, f_k = race_lookup(fp, vt, fps, bidx, impl="pallas")
     v_r, f_r = race_lookup_ref(fp, vt, fps, bidx)
-    np.testing.assert_array_equal(np.array(f_t), np.array(f_r))
-    np.testing.assert_allclose(np.array(v_t), np.array(v_r), atol=1e-6)
-
-
-def test_tiled_matches_scalar_fallback():
-    from repro.kernels.race_lookup.ops import race_lookup
-    from repro.kernels.race_lookup.ref import make_table
-
-    rng = np.random.RandomState(0)
-    keys = np.arange(1, 101)
-    vals = rng.randn(100, 128).astype(np.float32)
-    fp, vt, prep = make_table(64, 8, 128, keys, vals)
-    fps, bidx = prep(rng.randint(1, 300, 48))
-    v_t, f_t = race_lookup(fp, vt, fps, bidx, impl="pallas")
-    v_s, f_s = race_lookup(fp, vt, fps, bidx, impl="pallas_scalar")
-    np.testing.assert_array_equal(np.array(f_t), np.array(f_s))
-    np.testing.assert_allclose(np.array(v_t), np.array(v_s), atol=1e-6)
+    np.testing.assert_array_equal(np.array(f_k), np.array(f_r))
+    np.testing.assert_allclose(np.array(v_k), np.array(v_r), atol=1e-6)

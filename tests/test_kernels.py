@@ -127,8 +127,8 @@ def test_wkv_chunked_jnp_matches_sequential_strong_decay():
 ])
 def test_race_lookup_sharded_matches_per_shard_oracle(ns, nb, nslot, vdim):
     """Sharded kernel (grid dimension over shards, per-shard index map)
-    vs the per-shard ref oracle and the kept scalar fallback — including
-    ragged per-shard query counts and one shard with NO queries."""
+    vs the per-shard ref oracle — including ragged per-shard query counts
+    and one shard with NO queries."""
     from repro.kernels.race_lookup.ops import race_lookup_sharded
 
     rng = np.random.RandomState(ns * nb)
@@ -167,14 +167,10 @@ def test_race_lookup_sharded_matches_per_shard_oracle(ns, nb, nslot, vdim):
 
     v_sh, f_sh = race_lookup_sharded(fp_tables, val_tables, fps, bidx,
                                      qsidx, impl="pallas", qblock=16)
-    v_sc, f_sc = race_lookup_sharded(fp_tables, val_tables, fps, bidx,
-                                     qsidx, impl="pallas_scalar")
     v_rf, f_rf = race_lookup_sharded(fp_tables, val_tables, fps, bidx,
                                      qsidx, impl="ref")
     np.testing.assert_array_equal(np.array(f_sh), np.array(f_rf))
-    np.testing.assert_array_equal(np.array(f_sc), np.array(f_rf))
     np.testing.assert_allclose(np.array(v_sh), np.array(v_rf), atol=1e-6)
-    np.testing.assert_allclose(np.array(v_sc), np.array(v_rf), atol=1e-6)
     # ground truth: inserted keys found in THEIR shard's table only
     for i, (k, s) in enumerate(zip(qkeys, qsidx)):
         if int(k) in inserted[s]:

@@ -11,7 +11,6 @@ import pytest
 import jax
 
 from repro import obs
-from repro.kernels.race_lookup.ops import pallas_kernel
 from repro.kernels.race_lookup.race_lookup import group_by_shard
 from repro.kernels.race_lookup.ref import pool_lookup_ref
 from repro.kvs.race import (DeviceRaceTable, LookupStats, PoolRaceTable,
@@ -74,14 +73,14 @@ def test_span_args_read_back_and_only_stats_fields_count(tmp_path):
 
     def work():
         with obs.request(stats):
-            with obs.span("t.x", h2d_bytes=123, variant="tiled") as add:
+            with obs.span("t.x", h2d_bytes=123, variant="sharded") as add:
                 add(slots=40, qcap=8)
 
     _, events = traced(tmp_path, work)
     [(name, args)] = events
     assert name == "t.x"
     assert args == {"call": args["call"], "h2d_bytes": 123,
-                    "variant": "tiled", "slots": 40, "qcap": 8}
+                    "variant": "sharded", "slots": 40, "qcap": 8}
     # qcap and variant are no fields of the stats: arguments only
     assert stats == LookupStats(calls=1, h2d_bytes=123, slots=40)
 
@@ -109,7 +108,7 @@ def _filled(table, keys, vdim, seed):
         table.insert(int(k), rng.standard_normal(vdim).astype(np.float32))
 
 
-@pytest.mark.parametrize("impl", ["pallas", "pallas_scalar"])
+@pytest.mark.parametrize("impl", ["pallas", "ref"])
 def test_flat_table_spans_and_stats(tmp_path, impl):
     nb, nslot, vdim = 64, 8, 32
     table = DeviceRaceTable(n_buckets=nb, nslot=nslot, vdim=vdim)
@@ -125,8 +124,7 @@ def test_flat_table_spans_and_stats(tmp_path, impl):
     assert [[n for n, _ in c] for c in calls] == [
         ["race.prep", "race.to_device", "race.to_device", "race.kernel"],
         ["race.prep", "race.to_device", "race.kernel"]]
-    variant = (pallas_kernel((nb, nslot), (nb, nslot, vdim))
-               if impl == "pallas" else "scalar")
+    variant = "scalar" if impl == "pallas" else "ref"
     assert calls[0][3][1]["variant"] == calls[1][2][1]["variant"] == variant
     table_bytes = nb * nslot * 4 + nb * nslot * vdim * 4
     queries = len(keys) * (4 + 8)

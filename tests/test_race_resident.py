@@ -92,8 +92,8 @@ def test_an_insert_after_a_lookup_is_found_by_the_next(kind, via):
 
 
 @pytest.mark.parametrize("kind,impls", [
-    ("flat", ["pallas", "pallas_scalar", "pallas_tiled"]),
-    ("sharded", ["pallas", "pallas_scalar"])])
+    ("flat", ["pallas"]),
+    ("sharded", ["pallas"])])
 def test_every_impl_matches_ref_on_resident_tables(kind, impls):
     table = _table(kind)
     _fill(table, range(1, 150), seed=3)

@@ -1,35 +1,37 @@
 """The main path's Pallas kernels compile for a TPU v5e (Mosaic), at the
-widths chip_smoke.py drives them with: RACE lookup at vdim 256 (scalar at
-the flat table's size, tiled at one shard's size, sharded at the store's
-shard geometry), the pool-layout lookup at one memory node's 8M records
-with its index and pool in HBM, and the serverless stage gather at chunk
-128.
+benchmark's sizes (``bench/configs``, ``bench/traffic``): the flat
+table's lookup kernel at ``race-flat-1m-1kb``'s 262,139 buckets, the
+sharded kernel at ``race-sharded-1m-1kb``'s shard geometry, both at
+vdim 256 and 4096-key multi-gets, the pool-layout lookup at one memory
+node's 8M records with its index and pool in HBM, and the serverless
+stage gather at chunk 128.
 
 Nothing runs: the chip is described, not attached, so these compile
 from shapes alone and check that Mosaic accepts each kernel."""
 
 import functools
+import json
 import os
-import sys
+from pathlib import Path
 
 import pytest
 
 import jax
 import jax.numpy as jnp
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+from repro.kernels.race_lookup.pool import LANES, pool_lookup_pallas
+from repro.kernels.race_lookup.race_lookup import (race_lookup_pallas,
+                                                   sharded_lookup_call)
+from repro.kernels.serverless_stage.stage import CHUNK, chunk_gather_pallas
 
-import chip_smoke as cs  # noqa: E402
-from repro.kernels.race_lookup.pool import (  # noqa: E402
-    LANES, pool_lookup_pallas)
-from repro.kernels.race_lookup.race_lookup import (  # noqa: E402
-    race_lookup_pallas, race_lookup_pallas_tiled, sharded_lookup_call)
-from repro.kernels.serverless_stage.stage import (  # noqa: E402
-    CHUNK, chunk_gather_pallas)
-
-NQ = cs.BATCH
-#: the race-pool-8m-1kb deployment: 8M KV blocks, load 0.48
-POOL_RECORDS, POOL_BUCKETS = 8_000_000, 2_083_339
+BENCH = Path(__file__).resolve().parent.parent / "bench"
+FLAT, SHARDED, POOL = (
+    json.loads((BENCH / f"configs/race-{name}-1kb.json").read_text())
+    for name in ("flat-1m", "sharded-1m", "pool-8m"))
+NQ = json.loads(
+    (BENCH / "traffic/ycsbc-zipf.json").read_text())["multiget_keys"]
+NSLOT, VDIM = FLAT["slots_per_bucket"], FLAT["vdim"]
+POOL_RECORDS, POOL_BUCKETS = POOL["recordcount"], POOL["buckets"]
 SLAB_CHUNKS = 16 * (1024 // 4 // CHUNK)      # one chain slab of 16 x 1 KB
 
 
@@ -63,36 +65,26 @@ def shape_on_chip(topo):
 
 
 def _scalar(s):
-    nb = cs.FLAT_BUCKETS
+    nb = FLAT["buckets"]
     return jax.jit(functools.partial(race_lookup_pallas, interpret=False)
-                   ).lower(s((nb, cs.NSLOT)),
-                           s((nb, cs.NSLOT, cs.VDIM), jnp.float32),
-                           s((NQ,)), s((NQ, 2)))
-
-
-def _tiled(s):
-    nb = cs.SHARD_BUCKETS
-    return jax.jit(functools.partial(race_lookup_pallas_tiled,
-                                     interpret=False)
-                   ).lower(s((nb, cs.NSLOT)),
-                           s((nb, cs.NSLOT, cs.VDIM), jnp.float32),
+                   ).lower(s((nb, NSLOT)), s((nb, NSLOT, VDIM), jnp.float32),
                            s((NQ,)), s((NQ, 2)))
 
 
 def _sharded(s):
-    ns, nb, qcap = cs.N_SHARDS, cs.SHARD_BUCKETS, 64
+    ns, nb, qcap = SHARDED["n_shards"], SHARDED["buckets_per_shard"], 64
     return sharded_lookup_call.lower(
-        s((ns, nb, cs.NSLOT)), s((ns, nb, cs.NSLOT, cs.VDIM), jnp.float32),
+        s((ns, nb, NSLOT)), s((ns, nb, NSLOT, VDIM), jnp.float32),
         s((ns, qcap)), s((ns, qcap, 2)), qblock=64, interpret=False)
 
 
 def _pool(s):
-    index_rows = -(-POOL_BUCKETS * 2 * cs.NSLOT // LANES)
-    return jax.jit(functools.partial(pool_lookup_pallas, nslot=cs.NSLOT,
+    index_rows = -(-POOL_BUCKETS * 2 * NSLOT // LANES)
+    return jax.jit(functools.partial(pool_lookup_pallas, nslot=NSLOT,
                                      interpret=False)
                    ).lower(s((index_rows, 1, LANES)),
                            s((-(-POOL_RECORDS // LANES), 1, LANES)),
-                           s((POOL_RECORDS, 1, cs.VDIM), jnp.float32),
+                           s((POOL_RECORDS, 1, VDIM), jnp.float32),
                            s((NQ,)), s((NQ,)), s((NQ, 2)))
 
 
@@ -103,9 +95,9 @@ def _stage(s):
                            s((SLAB_CHUNKS,)))
 
 
-@pytest.mark.parametrize("lower", [_scalar, _tiled, _sharded, _pool, _stage],
-                         ids=["race_scalar", "race_tiled", "race_sharded",
-                              "race_pool", "stage_gather"])
+@pytest.mark.parametrize("lower", [_scalar, _sharded, _pool, _stage],
+                         ids=["race_scalar", "race_sharded", "race_pool",
+                              "stage_gather"])
 def test_kernel_compiles_for_v5e(shape_on_chip, lower):
     compiled = lower(shape_on_chip).compile()
     assert "tpu_custom_call" in compiled.as_text()
