@@ -72,8 +72,8 @@ def race_lookup_sharded(fp_tables, val_tables, queries, bucket_idx,
     shards with a per-shard index map, ONE shard's table VMEM-resident per
     step) or ``"ref"`` (the pure-jnp oracle, shard by shard).
 
-    Not jit-wrapped: the per-shard grouping/scatter is data-dependent
-    (the inner pallas_call still executes the kernel body).
+    Not jit-wrapped: the per-shard grouping is data-dependent (the
+    kernel and the gather back into the keys' order are jitted inside).
     """
     if impl == "pallas":
         return race_lookup_pallas_sharded(fp_tables, val_tables, queries,

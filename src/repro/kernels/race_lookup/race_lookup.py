@@ -30,10 +30,10 @@ Two kernels live here, one per inline table layout:
     contraction runs at HIGHEST precision: a one-hot row then returns the
     stored f32 value bit-exact. Queries are grouped per shard host-side
     (:func:`group_by_shard`), padded to the tile size with null queries
-    (fingerprint 0 matches nothing), run through
-    :func:`sharded_lookup_call` (a pure function of shapes) and scattered
-    back to input order. The minor grid dimension iterates tiles within a
-    shard, so consecutive steps reuse the resident shard block.
+    (fingerprint 0 matches nothing), run through :func:`sharded_lookup_call`
+    (a pure function of shapes) and gathered into input order on the
+    device (:func:`in_key_order`). The minor grid dimension iterates tiles
+    within a shard, so consecutive steps reuse the resident shard block.
 
 Tile rule: every block's last two dims are multiples of (8, 128) or equal
 the array's, so the scalar kernel and the per-query blocks use 3-D views
@@ -270,12 +270,12 @@ def sharded_lookup_call(fp_tables, val_tables, q_g, b_g, *, qblock: int,
     return values, found[..., 0]
 
 
-def group_by_shard(queries, bucket_idx, shard_idx, ns: int, qblock: int):
-    """Host-side prep of the sharded kernel: group queries per shard with
-    a stable sort (preserving intra-shard order) and pad each shard to
-    QCAP, a multiple of the tile, with null queries (fingerprint 0
-    matches nothing). Returns (q_g (NS, QCAP), b_g (NS, QCAP, 2), pos
-    (NS, QCAP) original index per slot or -1, qblock)."""
+def _group_slots(queries, bucket_idx, shard_idx, ns: int, qblock: int):
+    """Group queries per shard with a stable sort (preserving intra-shard
+    order) and pad each shard to QCAP, a multiple of the tile, with null
+    queries (fingerprint 0 matches nothing). Returns (q_g (NS, QCAP), b_g
+    (NS, QCAP, 2), inv (NQ,) int32, qblock): ``inv[i]`` is key ``i``'s
+    flat slot ``shard * QCAP + within`` in the kernel's padded output."""
     q = np.asarray(queries, np.int32)
     b = np.asarray(bucket_idx, np.int32)
     s = np.asarray(shard_idx, np.int64)
@@ -288,11 +288,32 @@ def group_by_shard(queries, bucket_idx, shard_idx, ns: int, qblock: int):
     within = np.arange(len(q)) - starts[ss]
     q_g = np.zeros((ns, qcap), np.int32)
     b_g = np.zeros((ns, qcap, 2), np.int32)
-    pos = np.full((ns, qcap), -1, np.int64)
     q_g[ss, within] = q[order]
     b_g[ss, within] = b[order]
-    pos[ss, within] = order
+    inv = np.empty(len(q), np.int32)
+    inv[order] = ss * qcap + within
+    return q_g, b_g, inv, qblock
+
+
+def group_by_shard(queries, bucket_idx, shard_idx, ns: int, qblock: int):
+    """Host-side prep of the sharded kernel: the queries grouped and
+    padded per shard as :func:`sharded_lookup_call` takes them. Returns
+    (q_g (NS, QCAP), b_g (NS, QCAP, 2), pos (NS, QCAP) original index per
+    slot or -1, qblock)."""
+    q_g, b_g, inv, qblock = _group_slots(queries, bucket_idx, shard_idx,
+                                         ns, qblock)
+    pos = np.full(q_g.shape, -1, np.int64)
+    pos.reshape(-1)[inv] = np.arange(len(inv))
     return q_g, b_g, pos, qblock
+
+
+@jax.jit
+def in_key_order(values, found, inv):
+    """The sharded kernel's padded answers, (NS, QCAP, VDIM) and (NS,
+    QCAP), as (NQ, VDIM) and (NQ,) in the keys' order: a gather of the
+    rows ``inv`` (:func:`_group_slots`). Rows move whole, so the answers
+    stay bit-exact; padding slots are never read."""
+    return values.reshape(-1, values.shape[-1])[inv], found.reshape(-1)[inv]
 
 
 def race_lookup_pallas_sharded(fp_tables, val_tables, queries, bucket_idx,
@@ -303,18 +324,18 @@ def race_lookup_pallas_sharded(fp_tables, val_tables, queries, bucket_idx,
     ``fp_tables`` (NS, NB, NSLOT) int32; ``val_tables`` (NS, NB, NSLOT,
     VDIM); ``queries`` (NQ,) int32 fingerprints; ``bucket_idx`` (NQ, 2)
     int32 *intra-shard* bucket rows; ``shard_idx`` (NQ,) int32 owning
-    shard per query. Returns (values (NQ, VDIM), found (NQ,) int32) in
-    input order: :func:`group_by_shard`, then
-    :func:`sharded_lookup_call`, then a scatter back to input order. Not
-    jit-wrapped — the grouping is data-dependent.
+    shard per query. Returns device arrays (values (NQ, VDIM), found
+    (NQ,) int32) in input order: the queries grouped per shard on the
+    host, then on the device :func:`sharded_lookup_call` and
+    :func:`in_key_order`, so the padded answers never leave the device.
+    Not jit-wrapped — the grouping is data-dependent.
 
     Spans (:mod:`repro.obs`), in order: ``race.group`` (with the slots,
     the padding among them and QCAP), ``race.to_device`` (the grouped
-    queries, and the tables where they are passed as host arrays, until
-    they are on the device; device arrays count no bytes), ``race.kernel``
-    (dispatch), ``race.to_host`` (the padded answers back, which waits
-    for the kernel), ``race.scatter``, and ``race.to_device`` again (the
-    answers in input order).
+    queries and their slots in the output, and the tables where they are
+    passed as host arrays, until they are on the device; device arrays
+    count no bytes) and ``race.kernel`` (dispatch of the kernel and of
+    the gather into the keys' order; neither is waited for).
     """
     ns = fp_tables.shape[0]
     vdim = val_tables.shape[-1]
@@ -323,26 +344,14 @@ def race_lookup_pallas_sharded(fp_tables, val_tables, queries, bucket_idx,
         return (jnp.zeros((0, vdim), val_tables.dtype),
                 jnp.zeros((0,), jnp.int32))
     with obs.span("race.group") as add:
-        q_g, b_g, pos, qblock = group_by_shard(queries, bucket_idx,
-                                               shard_idx, ns, qblock)
-        add(slots=pos.size, padded_slots=pos.size - nq, qcap=pos.shape[1])
-    operands = (fp_tables, val_tables, q_g, b_g)
+        q_g, b_g, inv, qblock = _group_slots(queries, bucket_idx,
+                                             shard_idx, ns, qblock)
+        add(slots=q_g.size, padded_slots=q_g.size - nq, qcap=q_g.shape[1])
+    operands = (fp_tables, val_tables, q_g, b_g, inv)
     with obs.span("race.to_device", h2d_bytes=sum(
             a.nbytes for a in operands if isinstance(a, np.ndarray))):
-        operands = jax.block_until_ready([jnp.asarray(a) for a in operands])
+        *operands, inv = jax.block_until_ready(jax.device_put(operands))
     with obs.span("race.kernel", variant="sharded"):
         values, found = sharded_lookup_call(*operands, qblock=qblock,
                                             interpret=interpret)
-
-    with obs.span("race.to_host"):
-        vals_g = np.asarray(values)
-        found_g = np.asarray(found)
-    with obs.span("race.scatter"):
-        valid = pos >= 0
-        out_v = np.zeros((nq, vdim), vals_g.dtype)
-        out_f = np.zeros(nq, np.int32)
-        out_v[pos[valid]] = vals_g[valid]
-        out_f[pos[valid]] = found_g[valid]
-    with obs.span("race.to_device", h2d_bytes=out_v.nbytes + out_f.nbytes):
-        return jax.block_until_ready((jnp.asarray(out_v),
-                                      jnp.asarray(out_f)))
+        return in_key_order(values, found, inv)

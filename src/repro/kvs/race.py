@@ -550,8 +550,8 @@ class LookupStats:
     calls: int = 0
     #: keys asked for
     keys: int = 0
-    #: bytes copied from the host to the device: table ships, query
-    #: operands and (sharded) the answers
+    #: bytes copied from the host to the device: table ships and query
+    #: operands (sharded: the grouped queries and each key's output slot)
     h2d_bytes: int = 0
     #: whole-table copies to the device: one on the first lookup and on
     #: each lookup that follows an insert, none while the device copy is
@@ -680,7 +680,9 @@ class ShardedDeviceRaceTable(_Resident):
     shard's own ``insert`` writes the stacked arrays and moves
     ``version``. The device holds one copy of the stacked tables between
     lookups, shipped whole only when ``version`` has moved since the last
-    ship; otherwise only the grouped queries and the answers cross.
+    ship; otherwise only the grouped queries cross to the device, and
+    the answers, put in the keys' order there, are returned as device
+    arrays.
 
     Each ``lookup_batch`` is one :func:`repro.obs.request` counting into
     ``stats``: ``race.prep`` (hashing and shard routing), then

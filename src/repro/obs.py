@@ -41,10 +41,9 @@ with no span). Their spans:
                    its own, with ``table_ships``, only on a lookup that
                    follows an insert (or the first)
 ``race.kernel``    dispatch of the jitted lookup; ``variant`` is
-                   ``scalar``, ``sharded``, ``pool`` (or ``ref``)
-``race.to_host``   sharded: the padded answers back, waiting for the
-                   kernel
-``race.scatter``   sharded: answers back to the keys' order
+                   ``scalar``, ``sharded``, ``pool`` (or ``ref``). The
+                   sharded one also dispatches the gather that puts the
+                   padded answers in the keys' order on the device
 =================  ====================================================
 
 To see them, trace a stretch of calls and open the trace in a viewer::

@@ -33,14 +33,15 @@ def _table_bytes(table):
 
 def _operand_bytes(table, keys):
     """Bytes a lookup of ``keys`` ships besides the table: the query
-    operands (flat), or the grouped queries and the answers (sharded)."""
+    operands (flat), or the grouped queries and each key's slot in the
+    padded output (sharded)."""
     if isinstance(table, DeviceRaceTable):
         return len(keys) * (4 + 8)
     _, _, sidx = table.prep(keys)
     _, _, pos, _ = group_by_shard(np.zeros(len(keys), np.int32),
                                   np.zeros((len(keys), 2), np.int32),
                                   sidx, table.n_shards, 64)
-    return pos.size * (4 + 8) + len(keys) * (VDIM * 4 + 4)
+    return pos.size * (4 + 8) + len(keys) * 4
 
 
 def _bits(a):

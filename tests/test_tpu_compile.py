@@ -2,7 +2,8 @@
 benchmark's sizes (``bench/configs``, ``bench/traffic``): the flat
 table's lookup kernel at ``race-flat-1m-1kb``'s 262,139 buckets, the
 sharded kernel at ``race-sharded-1m-1kb``'s shard geometry, both at
-vdim 256 and 4096-key multi-gets, the pool-layout lookup at one memory
+vdim 256 and 4096-key multi-gets, with the gather that orders the
+sharded answers, the pool-layout lookup at one memory
 node's 8M records with its index and pool in HBM, and the serverless
 stage gather at chunk 128.
 
@@ -20,7 +21,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.race_lookup.pool import LANES, pool_lookup_pallas
-from repro.kernels.race_lookup.race_lookup import (race_lookup_pallas,
+from repro.kernels.race_lookup.race_lookup import (in_key_order,
+                                                   race_lookup_pallas,
                                                    sharded_lookup_call)
 from repro.kernels.serverless_stage.stage import CHUNK, chunk_gather_pallas
 
@@ -113,3 +115,17 @@ def test_the_pool_kernel_leaves_index_and_pool_where_they_are(
         if " copy(" in line or "copy-start(" in line:
             assert not any(f"%{p}" in line.split("copy", 1)[1]
                            for p in ("index", "keys", "pool")), line
+
+
+def test_the_sharded_answers_are_ordered_by_a_plain_gather(shape_on_chip):
+    """The padded answers of a Zipfian multi-get (QCAP 256) go into the
+    keys' order on the chip by an XLA gather: no Pallas kernel, and a
+    name that the kernel's trace metrics do not match."""
+    ns, qcap = SHARDED["n_shards"], 256
+    s = shape_on_chip
+    text = in_key_order.lower(s((ns, qcap, VDIM), jnp.float32),
+                              s((ns, qcap)), s((NQ,))).compile().as_text()
+    assert "HloModule jit_in_key_order" in text and "gather(" in text
+    assert "tpu_custom_call" not in text
+    assert not any(f"%{k}" in text
+                   for k in ("race_lookup", "sharded_lookup_call"))
