@@ -7,8 +7,10 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
-from .race_lookup import race_lookup_pallas, race_lookup_pallas_sharded
+from .race_lookup import (in_key_order, race_lookup_pallas,
+                          race_lookup_pallas_sharded)
 from .pool import pool_lookup_pallas
 from .ref import pool_lookup_ref, race_lookup_ref
 
@@ -58,6 +60,56 @@ def pool_lookup(index, keys, pool, qkeys, qfps, bucket_idx, *, nslot: int,
     else:
         raise ValueError(f"unknown impl {impl!r}")
     return values, found, jnp.sum(blocks)
+
+
+#: the mesh axis along which a pool's memory nodes are laid out
+NODE_AXIS = "node"
+
+
+@functools.partial(jax.jit, static_argnames=("mesh", "nslot", "impl"))
+def pool_mesh_lookup(index, keys, pool, qkeys, qfps, bucket_idx, inv, *,
+                     mesh, nslot: int, impl: str = "pallas"):
+    """RACE's two-level lookup over a pool of N memory nodes held on the
+    devices of ``mesh`` (``MeshPoolRaceTable``): one SPMD program.
+
+    index (N, ceil(NB * 2 * NSLOT / 128), 1, 128) i32, keys (N, ceil(CAP
+    / 128), 1, 128) i32 and pool (N, CAP, 1, VDIM), each node's tables
+    as :func:`pool_lookup` reads them; qkeys and qfps (N, QCAP) i32 and
+    bucket_idx (N, QCAP, 2) i32, the queries grouped and padded per node
+    (null queries have fingerprint 0). All of these are sharded along
+    their node axis over the mesh axis ``NODE_AXIS``, so each device
+    holds N / D whole nodes. inv (NQ,) i32, replicated: each key's flat
+    slot ``node * QCAP + within`` in the padded answers.
+
+    Each device runs :func:`pool_lookup` for each of its nodes on that
+    node's tables, an all-gather over the mesh brings every node's
+    padded answers to every device, and :func:`in_key_order` puts them
+    in the keys' order there. Returns (values (NQ, VDIM), found (NQ,)
+    i32, the KV blocks fetched over all nodes, an i32 scalar), each
+    replicated over the mesh: the padded answers never leave the
+    devices. ``impl`` is :func:`pool_lookup`'s, per node."""
+    def per_device(index, keys, pool, qkeys, qfps, bucket_idx, inv):
+        values, found, blocks = [], [], 0
+        for i in range(index.shape[0]):
+            v, f, b = pool_lookup(index[i], keys[i], pool[i], qkeys[i],
+                                  qfps[i], bucket_idx[i], nslot=nslot,
+                                  impl=impl)
+            values.append(v)
+            found.append(f)
+            blocks = blocks + b
+        values, found = (jax.lax.all_gather(jnp.stack(a), NODE_AXIS,
+                                            tiled=True)
+                         for a in (values, found))
+        values, found = in_key_order(values, found, inv)
+        return values, found, jax.lax.psum(blocks, NODE_AXIS)
+
+    node, replicated = P(NODE_AXIS), P()
+    # the kernel's outputs carry no varying-axes type: the all-gather and
+    # the psum make every output the same on every device
+    return jax.shard_map(per_device, mesh=mesh, in_specs=(node,) * 6
+                         + (replicated,), out_specs=(replicated,) * 3,
+                         check_vma=False)(index, keys, pool, qkeys, qfps,
+                                          bucket_idx, inv)
 
 
 def race_lookup_sharded(fp_tables, val_tables, queries, bucket_idx,

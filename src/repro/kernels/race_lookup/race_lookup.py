@@ -275,7 +275,10 @@ def _group_slots(queries, bucket_idx, shard_idx, ns: int, qblock: int):
     order) and pad each shard to QCAP, a multiple of the tile, with null
     queries (fingerprint 0 matches nothing). Returns (q_g (NS, QCAP), b_g
     (NS, QCAP, 2), inv (NQ,) int32, qblock): ``inv[i]`` is key ``i``'s
-    flat slot ``shard * QCAP + within`` in the kernel's padded output."""
+    flat slot ``shard * QCAP + within`` in the kernel's padded output.
+    The pool over a mesh of memory nodes (``MeshPoolRaceTable``) groups
+    its queries per node with it too, a node for a shard, to the pool
+    kernel's tile."""
     q = np.asarray(queries, np.int32)
     b = np.asarray(bucket_idx, np.int32)
     s = np.asarray(shard_idx, np.int64)
@@ -312,7 +315,9 @@ def in_key_order(values, found, inv):
     """The sharded kernel's padded answers, (NS, QCAP, VDIM) and (NS,
     QCAP), as (NQ, VDIM) and (NQ,) in the keys' order: a gather of the
     rows ``inv`` (:func:`_group_slots`). Rows move whole, so the answers
-    stay bit-exact; padding slots are never read."""
+    stay bit-exact; padding slots are never read. The pool over a mesh of
+    memory nodes orders its nodes' gathered answers with it too, inside
+    its SPMD program (``ops.pool_mesh_lookup``)."""
     return values.reshape(-1, values.shape[-1])[inv], found.reshape(-1)[inv]
 
 

@@ -22,6 +22,10 @@ Bucket layout in storage-node memory (binary, little-endian):
   8-byte slots (fingerprint, length, pointer) over a pool of KV blocks
   that carry their keys, both in HBM, read by the two-level pool kernel.
 
+* :class:`MeshPoolRaceTable` — RACE's pool over several memory nodes,
+  one :class:`PoolRaceTable` each, laid out over a mesh of devices and
+  read by one SPMD program that routes each key to its node.
+
 * :class:`ShardClient` / :class:`ShardedDeviceRaceTable` — the
   shard-aware deployments: a store is ONE SHARD of the elastic dkv
   service (``src/repro/dkv``), addressed through the shard directory by
@@ -587,10 +591,17 @@ class _Resident:
             self._dev = None        # free the stale copy before the ship
             host = self.tables()
             with obs.span("race.to_device", table_ships=1,
-                          h2d_bytes=sum(a.nbytes for a in host)):
-                self._dev = jax.block_until_ready(jax.device_put(host))
+                          h2d_bytes=sum(a.nbytes
+                                        for a in jax.tree.leaves(host))):
+                self._dev = jax.block_until_ready(self._put(host))
             self._dev_version = version
         return self._dev
+
+    def _put(self, host) -> Tuple:
+        """The device copy of the host tables ``host``: on the default
+        device."""
+        import jax
+        return jax.device_put(host)
 
 
 class DeviceRaceTable(_Resident):
@@ -893,4 +904,167 @@ class PoolRaceTable(_Resident):
                     index, bkeys, pool, *operands, nslot=self.nslot,
                     impl=impl)
                 self.stats.blocks = self.stats.blocks + blocks
+            return values, found
+
+
+class MeshPoolRaceTable(_Resident):
+    """RACE's pool over ``n_nodes`` memory nodes, laid out over a mesh of
+    devices: one :class:`PoolRaceTable` of one geometry per node, each
+    holding the keys that the dkv shard map (:func:`shard_of_keys`)
+    sends it. ``capacity`` is per node.
+
+    Placement: the devices form a 1-D mesh, ``mesh``, along
+    ``NODE_AXIS``; their number divides ``n_nodes``, and device ``d``
+    holds nodes ``d * n_nodes / D`` up to the next device's first, whole
+    (one node per device where there are as many devices as nodes).
+
+    Residency: the nodes' host arrays are the table; ``insert`` and
+    ``insert_many`` route each key to its node, where it is placed as
+    that node's own ``insert_many`` places it, so each node's index,
+    keys and pool are exactly those of a :class:`PoolRaceTable` loaded
+    with the keys routed to it, in their order. ``version`` sums the
+    nodes'. The devices hold one copy between lookups: node ``i``'s
+    ``tables()`` on its device, assembled into index, keys and pool
+    arrays with a leading node axis sharded over the mesh, shipped whole
+    (no host stacking) only when ``version`` has moved.
+
+    Each ``lookup_batch`` is one :func:`repro.obs.request` counting into
+    ``stats``, in :class:`PoolRaceTable`'s span order: ``race.prep``
+    (hashing and node routing), ``race.to_device`` (the tables, only
+    when they ship, with ``table_ships``), ``race.group`` (the queries
+    grouped and padded per node to the pool kernel's tile, as the
+    sharded kernel's are; ``slots``, ``padded_slots``, ``qcap``), then
+    :meth:`lookup_grouped`'s ``race.to_device`` (the grouped queries,
+    sharded on the mesh, and each key's slot in the padded answers,
+    replicated) and ``race.kernel`` (``variant="pool_mesh"``: dispatch
+    of one SPMD program, :func:`~repro.kernels.race_lookup.ops.
+    pool_mesh_lookup`, that runs the pool kernel per node and gathers
+    the answers into the keys' order across the devices). The answers
+    come back as device arrays; ``stats.blocks`` sums on the devices the
+    KV blocks fetched on every node."""
+
+    def __init__(self, n_nodes: int = 4, n_buckets: int = 1024,
+                 nslot: int = 8, vdim: int = 128, capacity: int = 4096,
+                 devices=None):
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        from repro.kernels.race_lookup.ops import NODE_AXIS
+        devices = jax.devices() if devices is None else list(devices)
+        if n_nodes % len(devices):
+            raise ValueError(f"{n_nodes} nodes do not divide over "
+                             f"{len(devices)} devices")
+        self.n_nodes = n_nodes
+        self.n_buckets = n_buckets
+        self.nslot = nslot
+        self.vdim = vdim
+        self.nodes = [PoolRaceTable(n_buckets, nslot, vdim, capacity)
+                      for _ in range(n_nodes)]
+        self.mesh = Mesh(np.array(devices), (NODE_AXIS,))
+        self._by_node = NamedSharding(self.mesh, P(NODE_AXIS))
+        self._replicated = NamedSharding(self.mesh, P())
+        self.stats = LookupStats()
+        # on the mesh, as every lookup's block count is: one compiled add
+        self.stats.blocks = jax.device_put(jnp.zeros((), jnp.int32),
+                                           self._replicated)
+
+    @property
+    def version(self) -> int:
+        """Inserts so far, over every node."""
+        return sum(n.version for n in self.nodes)
+
+    def insert(self, key: int, value: np.ndarray) -> None:
+        self.insert_many(np.array([key]), np.asarray(value)[None])
+
+    def insert_many(self, keys: np.ndarray, values: np.ndarray) -> None:
+        """Route ``keys`` to their nodes and insert each node's share, in
+        order, with that node's ``insert_many``. A node that refuses its
+        share (pool full, bucket overflow) raises; the nodes before it
+        keep theirs."""
+        keys = np.asarray(keys)
+        node = shard_of_keys(keys, self.n_nodes)
+        for i, table in enumerate(self.nodes):
+            mine = node == i
+            if mine.any():
+                table.insert_many(keys[mine], values[mine])
+
+    def prep(self, keys: np.ndarray
+             ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
+        """(keys (NQ,) i32, 8-bit fingerprints (NQ,) i32, candidate
+        buckets (NQ, 2) i32 within the node, node (NQ,) i32); every node
+        shares one bucket geometry."""
+        return (*self.nodes[0].prep(keys), shard_of_keys(keys, self.n_nodes))
+
+    def tables(self) -> List[Tuple[np.ndarray, np.ndarray, np.ndarray]]:
+        """Each node's host ``(index, keys, pool)``, in node order."""
+        return [n.tables() for n in self.nodes]
+
+    def _put(self, host) -> Tuple:
+        """(index, keys, pool) as arrays with a leading node axis, sharded
+        over the mesh: each node's arrays are put on its device alone."""
+        import jax
+        import jax.numpy as jnp
+        devices = self.mesh.devices.tolist()
+        per = self.n_nodes // len(devices)
+        out = []
+        for arrays in zip(*host):            # every node's index, then ...
+            shards = []
+            for d, device in enumerate(devices):
+                parts = jax.device_put(
+                    [a[None] for a in arrays[d * per:(d + 1) * per]], device)
+                shards.append(parts[0] if per == 1 else jnp.concatenate(parts))
+            out.append(jax.make_array_from_single_device_arrays(
+                (self.n_nodes, *arrays[0].shape), self._by_node, shards))
+        return tuple(out)
+
+    def lookup_grouped(self, qkeys: np.ndarray, qfps: np.ndarray,
+                       bucket_idx: np.ndarray, inv: np.ndarray,
+                       impl: str = "pallas"):
+        """The device half of ``lookup_batch``, on queries already grouped
+        per node: qkeys, qfps (N, QCAP) i32, bucket_idx (N, QCAP, 2) i32,
+        inv (NQ,) i32 (:func:`~repro.kernels.race_lookup.ops.
+        pool_mesh_lookup`'s operands). Puts them on the mesh, ships the
+        tables first if they are stale, and dispatches the program.
+        Returns device arrays (values (NQ, VDIM), found (NQ,) i32, blocks
+        fetched, an i32 scalar)."""
+        import jax
+
+        from repro import obs
+        from repro.kernels.race_lookup.ops import pool_mesh_lookup
+        tables = self._ship_if_stale()
+        grouped = (qkeys, qfps, bucket_idx)
+        with obs.span("race.to_device", h2d_bytes=sum(
+                a.nbytes for a in grouped) + inv.nbytes * self.mesh.size):
+            grouped, inv = jax.block_until_ready((
+                jax.device_put(grouped, self._by_node),
+                jax.device_put(inv, self._replicated)))
+        with obs.span("race.kernel",
+                      variant="pool_mesh" if impl == "pallas" else impl):
+            return pool_mesh_lookup(*tables, *grouped, inv, mesh=self.mesh,
+                                    nslot=self.nslot, impl=impl)
+
+    def lookup_batch(self, keys: np.ndarray, impl: str = "pallas"):
+        import jax.numpy as jnp
+
+        from repro import obs
+        from repro.kernels.race_lookup.pool import QBLOCK
+        from repro.kernels.race_lookup.race_lookup import _group_slots
+        with obs.request(self.stats):
+            with obs.span("race.prep", keys=len(keys)):
+                qkeys, qfps, bidx, node = self.prep(keys)
+            self._ship_if_stale()
+            if not len(qkeys):
+                return (jnp.zeros((0, self.vdim), jnp.float32),
+                        jnp.zeros((0,), jnp.int32))
+            with obs.span("race.group") as add:
+                qfps, bidx, inv, _ = _group_slots(qfps, bidx, node,
+                                                  self.n_nodes, QBLOCK)
+                grouped_keys = np.zeros(qfps.size, np.int32)
+                grouped_keys[inv] = qkeys
+                add(slots=qfps.size, padded_slots=qfps.size - len(qkeys),
+                    qcap=qfps.shape[1])
+            values, found, blocks = self.lookup_grouped(
+                grouped_keys.reshape(qfps.shape), qfps, bidx, inv, impl)
+            self.stats.blocks = self.stats.blocks + blocks
             return values, found

@@ -27,23 +27,26 @@ is their parent.
 The device RACE tables (``repro.kvs.race``) open one request per
 ``lookup_batch`` and count into their ``stats`` (``LookupStats``:
 ``calls``, ``keys``, ``h2d_bytes``, ``table_ships``, ``slots``,
-``padded_slots``, and ``blocks``, which the pool table sums on the device
+``padded_slots``, and ``blocks``, which the pool tables sum on the device
 with no span). Their spans:
 
 =================  ====================================================
-``race.prep``      host hashing (and shard routing); ``keys``
+``race.prep``      host hashing (and shard or node routing); ``keys``
 ``race.stack``     sharded: the resident stacked tables handed to the
                    kernel path (no copy)
-``race.group``     sharded: queries grouped and padded per shard;
-                   ``slots``, ``padded_slots``, ``qcap``
+``race.group``     sharded, pool mesh: queries grouped and padded per
+                   shard or node; ``slots``, ``padded_slots``, ``qcap``
 ``race.to_device`` host-to-device copies, until they are on the device;
                    ``h2d_bytes``. The whole table ships in a span of
                    its own, with ``table_ships``, only on a lookup that
                    follows an insert (or the first)
 ``race.kernel``    dispatch of the jitted lookup; ``variant`` is
-                   ``scalar``, ``sharded``, ``pool`` (or ``ref``). The
-                   sharded one also dispatches the gather that puts the
-                   padded answers in the keys' order on the device
+                   ``scalar``, ``sharded``, ``pool``, ``pool_mesh`` (or
+                   ``ref``). The sharded one also dispatches the gather
+                   that puts the padded answers in the keys' order on
+                   the device; ``pool_mesh`` is one SPMD program that
+                   runs the pool kernel on every node and gathers the
+                   answers into the keys' order across the devices
 =================  ====================================================
 
 To see them, trace a stretch of calls and open the trace in a viewer::

@@ -1,7 +1,8 @@
 """The contract every device RACE table keeps, whichever layout it holds
 and whichever ``impl`` serves it: ``DeviceRaceTable`` (flat),
-``ShardedDeviceRaceTable`` (the dkv shard map) and ``PoolRaceTable``
-(RACE's slots over a pool of KV blocks), each with its kernel
+``ShardedDeviceRaceTable`` (the dkv shard map), ``PoolRaceTable``
+(RACE's slots over a pool of KV blocks) and ``MeshPoolRaceTable`` (that
+pool over four memory nodes, here all on one device), each with its kernel
 (``"pallas"``) and its pure-jnp oracle (``"ref"``). Every answer is held
 to a plain dictionary of the inserted records, bit for bit. CPU, tiny
 tables (Pallas in interpret mode)."""
@@ -9,12 +10,12 @@ tables (Pallas in interpret mode)."""
 import numpy as np
 import pytest
 
-from repro.kvs.race import (DeviceRaceTable, PoolRaceTable,
-                            ShardedDeviceRaceTable)
+from repro.kvs.race import (DeviceRaceTable, MeshPoolRaceTable,
+                            PoolRaceTable, ShardedDeviceRaceTable)
 
 VDIM = 32
 N_RECORDS = 300
-TABLES = ["flat", "sharded", "pool"]
+TABLES = ["flat", "sharded", "pool", "pool_mesh"]
 
 
 def _table(kind):
@@ -23,7 +24,10 @@ def _table(kind):
     if kind == "sharded":
         return ShardedDeviceRaceTable(n_shards=3, n_buckets=37, nslot=8,
                                       vdim=VDIM)
-    return PoolRaceTable(n_buckets=101, nslot=8, vdim=VDIM, capacity=400)
+    if kind == "pool":
+        return PoolRaceTable(n_buckets=101, nslot=8, vdim=VDIM, capacity=400)
+    return MeshPoolRaceTable(n_nodes=4, n_buckets=37, nslot=8, vdim=VDIM,
+                             capacity=120)
 
 
 def _records(seed=1):
@@ -33,7 +37,7 @@ def _records(seed=1):
 
 
 def _load(table, keys, vals):
-    if isinstance(table, PoolRaceTable):
+    if isinstance(table, (PoolRaceTable, MeshPoolRaceTable)):
         table.insert_many(keys, vals)
     else:
         for k, v in zip(keys.tolist(), vals):
@@ -96,7 +100,7 @@ def test_a_table_answers_as_its_records(kind, impl, case):
     np.testing.assert_array_equal(_bits(v), _bits(want))
     if case == "mixed_ragged":
         assert len(asked) == 77 and 0 < f.sum() < len(asked)
-    if case == "empty_table" and kind == "pool":
+    if case == "empty_table" and kind.startswith("pool"):
         assert int(table.stats.blocks) == 0     # no slot matched
     assert table.stats.calls == 1 and table.stats.keys == len(asked)
 

@@ -4,8 +4,9 @@ table's lookup kernel at ``race-flat-1m-1kb``'s 262,139 buckets, the
 sharded kernel at ``race-sharded-1m-1kb``'s shard geometry, both at
 vdim 256 and 4096-key multi-gets, with the gather that orders the
 sharded answers, the pool-layout lookup at one memory
-node's 8M records with its index and pool in HBM, and the serverless
-stage gather at chunk 128.
+node's 8M records with its index and pool in HBM, the SPMD program of
+the pool over four memory nodes at 4 x 8M records on a described
+four-chip host, and the serverless stage gather at chunk 128.
 
 Nothing runs: the chip is described, not attached, so these compile
 from shapes alone and check that Mosaic accepts each kernel."""
@@ -20,6 +21,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.race_lookup import pool as pool_kernel
+from repro.kernels.race_lookup.ops import NODE_AXIS, pool_mesh_lookup
 from repro.kernels.race_lookup.pool import LANES, pool_lookup_pallas
 from repro.kernels.race_lookup.race_lookup import (in_key_order,
                                                    race_lookup_pallas,
@@ -27,9 +30,9 @@ from repro.kernels.race_lookup.race_lookup import (in_key_order,
 from repro.kernels.serverless_stage.stage import CHUNK, chunk_gather_pallas
 
 BENCH = Path(__file__).resolve().parent.parent / "bench"
-FLAT, SHARDED, POOL = (
+FLAT, SHARDED, POOL, MESH = (
     json.loads((BENCH / f"configs/race-{name}-1kb.json").read_text())
-    for name in ("flat-1m", "sharded-1m", "pool-8m"))
+    for name in ("flat-1m", "sharded-1m", "pool-8m", "pool-4x8m"))
 NQ = json.loads(
     (BENCH / "traffic/ycsbc-zipf.json").read_text())["multiget_keys"]
 NSLOT, VDIM = FLAT["slots_per_bucket"], FLAT["vdim"]
@@ -129,3 +132,49 @@ def test_the_sharded_answers_are_ordered_by_a_plain_gather(shape_on_chip):
     assert "tpu_custom_call" not in text
     assert not any(f"%{k}" in text
                    for k in ("race_lookup", "sharded_lookup_call"))
+
+
+def test_the_pool_over_four_nodes_compiles_one_node_a_chip(topo,
+                                                           shape_on_chip,
+                                                           monkeypatch):
+    """The SPMD lookup of ``race-pool-4x8m-1kb`` on a described four-chip
+    v5e: each chip holds one node's 8M-record index and pool and runs the
+    pool kernel (named ``pool_lookup`` in the HLO, which the trace
+    metrics match) on its node's queries, at the busiest node's padded
+    size for a Zipfian 4096-key multi-get; a collective brings the
+    answers to every chip. One node's tables and the program's own
+    buffers fit a chip's 16 GB, with no copy of a pool."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    import numpy as np
+
+    # the kernel compiles for the described chip, not in interpret mode
+    monkeypatch.setattr(pool_kernel, "interpret_mode", lambda: False)
+    n, nb, qcap = MESH["nodes"], MESH["buckets"], 1280
+    cap = -(-MESH["recordcount"] // n) + 4096      # the busiest node's
+    mesh = Mesh(np.array(topo.devices[:n]), (NODE_AXIS,))
+    by_node, replicated = (NamedSharding(mesh, P(NODE_AXIS)),
+                           NamedSharding(mesh, P()))
+
+    def s(shape, dtype=jnp.int32, sharding=by_node):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    compiled = pool_mesh_lookup.lower(
+        s((n, -(-nb * 2 * NSLOT // LANES), 1, LANES)),
+        s((n, -(-cap // LANES), 1, LANES)),
+        s((n, cap, 1, MESH["vdim"]), jnp.float32),
+        s((n, qcap)), s((n, qcap)), s((n, qcap, 2)),
+        s((NQ,), sharding=replicated), mesh=mesh,
+        nslot=MESH["slots_per_bucket"]).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert any(f" {op}(" in text or f" {op}-start(" in text
+               for op in ("all-gather", "all-to-all", "all-reduce",
+                          "collective-permute"))
+    assert any(line.lstrip().startswith("%pool_lookup")
+               and 'custom_call_target="tpu_custom_call"' in line
+               for line in text.splitlines())
+    mem = compiled.memory_analysis()
+    per_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes)
+    assert 8e9 < per_chip < 16e9
+    assert mem.temp_size_in_bytes < 1e9          # no copy of a pool
