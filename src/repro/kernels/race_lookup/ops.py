@@ -11,7 +11,7 @@ from jax.sharding import PartitionSpec as P
 
 from .race_lookup import (in_key_order, race_lookup_pallas,
                           race_lookup_pallas_sharded)
-from .pool import pool_lookup_pallas
+from .pool import LANES, pool_lookup_pallas
 from .ref import pool_lookup_ref, race_lookup_ref
 
 
@@ -36,19 +36,51 @@ def race_lookup(fp_table, val_table, queries, bucket_idx,
     raise ValueError(f"unknown impl {impl!r}")
 
 
-@functools.partial(jax.jit, static_argnames=("nslot", "impl"))
-def pool_lookup(index, keys, pool, qkeys, qfps, bucket_idx, *, nslot: int,
+def pack_queries(qkeys, qfps, bucket_idx, inv=None) -> np.ndarray:
+    """The pool layout's query operands as one int32 array, for one
+    host-to-device put: ``(..., R, L)``, the queries along the last
+    (lane) axis, zero-padded to ``L``, the next multiple of 128 lanes (a
+    minor axis of 128s needs no relayout into the device's tiles, as a
+    minor axis of 2 does).
+
+    Rows: ``qkeys``, ``qfps``, the first and the second candidate bucket
+    of ``bucket_idx``, each query in the same lane: :func:`pool_lookup`'s
+    operands. qkeys and qfps are (..., Q) i32, bucket_idx (..., Q, 2).
+    Given ``inv`` (NQ,) i32, queries grouped per node (a leading axis of
+    N nodes) take a fifth row: node ``i``'s chunk of ``inv``, keys ``i *
+    C`` up to ``(i + 1) * C`` with ``C = ceil(NQ / N)``, zero-padded
+    (:func:`pool_mesh_lookup`'s operands). Sharded along its node axis,
+    the array is one device buffer per device, however many the mesh
+    has."""
+    rows = [qkeys, qfps, bucket_idx[..., 0], bucket_idx[..., 1]]
+    if inv is not None:
+        n = np.shape(qkeys)[0]
+        chunk = -(-len(inv) // n)
+        rows.append(np.pad(inv, (0, n * chunk - len(inv))).reshape(n, chunk))
+    lanes = -(-max(np.shape(r)[-1] for r in rows) // LANES) * LANES
+    out = np.zeros((*np.shape(qkeys)[:-1], len(rows), lanes), np.int32)
+    for i, row in enumerate(rows):
+        out[..., i, :np.shape(row)[-1]] = row
+    return out
+
+
+@functools.partial(jax.jit, static_argnames=("nq", "nslot", "impl"))
+def pool_lookup(index, keys, pool, queries, *, nq: int, nslot: int,
                 impl: str = "pallas"):
     """RACE's two-level lookup over the pool layout (``PoolRaceTable``):
     both candidate buckets from the index, the KV block of each
     fingerprint match, the record whose stored key is the query key.
 
     index (ceil(NB * 2 * NSLOT / 128), 1, 128) i32, keys (ceil(CAP /
-    128), 1, 128) i32, pool (CAP, 1, VDIM), qkeys (NQ,) i32, qfps (NQ,)
-    i32 8-bit fingerprints, bucket_idx (NQ, 2) i32 -> (values (NQ,
-    VDIM), found (NQ,) i32, the KV blocks fetched over the batch, an i32
-    scalar). ``impl``: ``"pallas"`` (the kernel, index and pool left in
-    HBM) or ``"ref"`` (the pure-jnp oracle)."""
+    128), 1, 128) i32, pool (CAP, 1, VDIM); queries (4, L) i32, the NQ
+    queries packed as :func:`pack_queries` packs them (query keys, their
+    8-bit fingerprints, first and second candidate bucket, in lanes 0 to
+    NQ - 1), unpacked here on the device -> (values (NQ, VDIM), found
+    (NQ,) i32, the KV blocks fetched over the batch, an i32 scalar).
+    ``impl``: ``"pallas"`` (the kernel, index and pool left in HBM) or
+    ``"ref"`` (the pure-jnp oracle)."""
+    qkeys, qfps = queries[0, :nq], queries[1, :nq]
+    bucket_idx = jnp.stack([queries[2, :nq], queries[3, :nq]], axis=1)
     if impl == "ref":
         values, found, blocks = pool_lookup_ref(index, keys, pool, qkeys,
                                                 qfps, bucket_idx,
@@ -66,50 +98,56 @@ def pool_lookup(index, keys, pool, qkeys, qfps, bucket_idx, *, nslot: int,
 NODE_AXIS = "node"
 
 
-@functools.partial(jax.jit, static_argnames=("mesh", "nslot", "impl"))
-def pool_mesh_lookup(index, keys, pool, qkeys, qfps, bucket_idx, inv, *,
+@functools.partial(jax.jit,
+                   static_argnames=("nq", "qcap", "mesh", "nslot", "impl"))
+def pool_mesh_lookup(index, keys, pool, queries, *, nq: int, qcap: int,
                      mesh, nslot: int, impl: str = "pallas"):
     """RACE's two-level lookup over a pool of N memory nodes held on the
     devices of ``mesh`` (``MeshPoolRaceTable``): one SPMD program.
 
     index (N, ceil(NB * 2 * NSLOT / 128), 1, 128) i32, keys (N, ceil(CAP
     / 128), 1, 128) i32 and pool (N, CAP, 1, VDIM), each node's tables
-    as :func:`pool_lookup` reads them; qkeys and qfps (N, QCAP) i32 and
-    bucket_idx (N, QCAP, 2) i32, the queries grouped and padded per node
-    (null queries have fingerprint 0). All of these are sharded along
-    their node axis over the mesh axis ``NODE_AXIS``, so each device
-    holds N / D whole nodes. inv (NQ,) i32, replicated: each key's flat
-    slot ``node * QCAP + within`` in the padded answers.
+    as :func:`pool_lookup` reads them; queries (N, 5, L) i32, the NQ
+    keys' queries grouped and padded to QCAP per node (null queries have
+    fingerprint 0) and packed by :func:`pack_queries`: per node the four
+    rows :func:`pool_lookup` takes, then the node's chunk of ``inv``,
+    each key's flat slot ``node * QCAP + within`` in the padded answers.
+    All of these are sharded along their node axis over the mesh axis
+    ``NODE_AXIS``, so each device holds N / D whole nodes and the
+    queries cross to the devices as one buffer a device.
 
     Each device runs :func:`pool_lookup` for each of its nodes on that
-    node's tables, an all-gather over the mesh brings every node's
-    padded answers to every device, and :func:`in_key_order` puts them
-    in the keys' order there. Returns (values (NQ, VDIM), found (NQ,)
-    i32, the KV blocks fetched over all nodes, an i32 scalar), each
-    replicated over the mesh: the padded answers never leave the
-    devices. ``impl`` is :func:`pool_lookup`'s, per node."""
-    def per_device(index, keys, pool, qkeys, qfps, bucket_idx, inv):
+    node's tables and rows, an all-gather over the mesh brings every
+    node's chunk of ``inv`` and every node's padded answers to every
+    device, and :func:`in_key_order` puts the answers in the keys' order
+    there. Returns (values (NQ, VDIM), found (NQ,) i32, the KV blocks
+    fetched over all nodes, an i32 scalar), each replicated over the
+    mesh: the padded answers never leave the devices. ``impl`` is
+    :func:`pool_lookup`'s, per node."""
+    chunk = -(-nq // index.shape[0])
+
+    def per_device(index, keys, pool, queries):
         values, found, blocks = [], [], 0
         for i in range(index.shape[0]):
-            v, f, b = pool_lookup(index[i], keys[i], pool[i], qkeys[i],
-                                  qfps[i], bucket_idx[i], nslot=nslot,
+            v, f, b = pool_lookup(index[i], keys[i], pool[i],
+                                  queries[i, :4], nq=qcap, nslot=nslot,
                                   impl=impl)
             values.append(v)
             found.append(f)
             blocks = blocks + b
-        values, found = (jax.lax.all_gather(jnp.stack(a), NODE_AXIS,
-                                            tiled=True)
-                         for a in (values, found))
-        values, found = in_key_order(values, found, inv)
+        values, found, inv = (
+            jax.lax.all_gather(a, NODE_AXIS, tiled=True)
+            for a in (jnp.stack(values), jnp.stack(found),
+                      queries[:, 4, :chunk]))
+        values, found = in_key_order(values, found,
+                                     inv.reshape(-1)[:nq])
         return values, found, jax.lax.psum(blocks, NODE_AXIS)
 
-    node, replicated = P(NODE_AXIS), P()
     # the kernel's outputs carry no varying-axes type: the all-gather and
     # the psum make every output the same on every device
-    return jax.shard_map(per_device, mesh=mesh, in_specs=(node,) * 6
-                         + (replicated,), out_specs=(replicated,) * 3,
-                         check_vma=False)(index, keys, pool, qkeys, qfps,
-                                          bucket_idx, inv)
+    return jax.shard_map(per_device, mesh=mesh, in_specs=(P(NODE_AXIS),) * 4,
+                         out_specs=(P(),) * 3, check_vma=False)(
+                             index, keys, pool, queries)
 
 
 def race_lookup_sharded(fp_tables, val_tables, queries, bucket_idx,

@@ -557,6 +557,10 @@ class LookupStats:
     #: bytes copied from the host to the device: table ships and query
     #: operands (sharded: the grouped queries and each key's output slot)
     h2d_bytes: int = 0
+    #: pool layout: device buffers the host-to-device puts created, the
+    #: device shards of each array put (a lookup's packed queries: one a
+    #: device; a ship: one a host array)
+    h2d_buffers: int = 0
     #: whole-table copies to the device: one on the first lookup and on
     #: each lookup that follows an insert, none while the device copy is
     #: current
@@ -578,6 +582,8 @@ class _Resident:
 
     _dev: Optional[Tuple] = None
     _dev_version = -1
+    #: count ``stats.h2d_buffers`` (the pool tables)
+    counts_buffers = False
 
     def _ship_if_stale(self) -> Tuple:
         """The device copy of ``tables()``; shipped first, in a
@@ -590,9 +596,13 @@ class _Resident:
             from repro import obs
             self._dev = None        # free the stale copy before the ship
             host = self.tables()
-            with obs.span("race.to_device", table_ships=1,
-                          h2d_bytes=sum(a.nbytes
-                                        for a in jax.tree.leaves(host))):
+            leaves = jax.tree.leaves(host)
+            counts = {"table_ships": 1,
+                      "h2d_bytes": sum(a.nbytes for a in leaves)}
+            if self.counts_buffers:
+                # each host array goes whole to one device
+                counts["h2d_buffers"] = len(leaves)
+            with obs.span("race.to_device", **counts):
                 self._dev = jax.block_until_ready(self._put(host))
             self._dev_version = version
         return self._dev
@@ -797,9 +807,15 @@ class PoolRaceTable(_Resident):
     Each ``lookup_batch`` is one :func:`repro.obs.request` counting into
     ``stats``, with the spans of :class:`DeviceRaceTable` in the same
     order: ``race.prep``, ``race.to_device`` (the tables, only when they
-    ship, with ``table_ships``; then the query operands) and
-    ``race.kernel`` (``variant="pool"``). ``stats.blocks`` sums on the
-    device the KV blocks each lookup fetched, so no call waits for it."""
+    ship, with ``table_ships``; then the query operands, packed into one
+    array by :func:`~repro.kernels.race_lookup.ops.pack_queries` and put
+    as one device buffer, ``h2d_buffers``) and ``race.kernel``
+    (``variant="pool"``: :func:`~repro.kernels.race_lookup.ops.
+    pool_lookup`, which unpacks them on the device). ``stats.blocks``
+    sums on the device the KV blocks each lookup fetched, so no call
+    waits for it."""
+
+    counts_buffers = True
 
     def __init__(self, n_buckets: int = 1024, nslot: int = 8,
                  vdim: int = 128, capacity: int = 4096):
@@ -890,19 +906,21 @@ class PoolRaceTable(_Resident):
         import jax
 
         from repro import obs
-        from repro.kernels.race_lookup.ops import pool_lookup
+        from repro.kernels.race_lookup import ops
         with obs.request(self.stats):
             with obs.span("race.prep", keys=len(keys)):
                 operands = self.prep(keys)
             index, bkeys, pool = self._ship_if_stale()
-            with obs.span("race.to_device",
-                          h2d_bytes=sum(a.nbytes for a in operands)):
-                operands = jax.block_until_ready(jax.device_put(operands))
+            with obs.span("race.to_device") as add:
+                queries = ops.pack_queries(*operands)
+                add(h2d_bytes=queries.nbytes)
+                queries = jax.block_until_ready(jax.device_put(queries))
+                add(h2d_buffers=len(queries.sharding.device_set))
             with obs.span("race.kernel",
                           variant="pool" if impl == "pallas" else impl):
-                values, found, blocks = pool_lookup(
-                    index, bkeys, pool, *operands, nslot=self.nslot,
-                    impl=impl)
+                values, found, blocks = ops.pool_lookup(
+                    index, bkeys, pool, queries, nq=len(operands[0]),
+                    nslot=self.nslot, impl=impl)
                 self.stats.blocks = self.stats.blocks + blocks
             return values, found
 
@@ -934,14 +952,19 @@ class MeshPoolRaceTable(_Resident):
     when they ship, with ``table_ships``), ``race.group`` (the queries
     grouped and padded per node to the pool kernel's tile, as the
     sharded kernel's are; ``slots``, ``padded_slots``, ``qcap``), then
-    :meth:`lookup_grouped`'s ``race.to_device`` (the grouped queries,
-    sharded on the mesh, and each key's slot in the padded answers,
-    replicated) and ``race.kernel`` (``variant="pool_mesh"``: dispatch
-    of one SPMD program, :func:`~repro.kernels.race_lookup.ops.
-    pool_mesh_lookup`, that runs the pool kernel per node and gathers
-    the answers into the keys' order across the devices). The answers
-    come back as device arrays; ``stats.blocks`` sums on the devices the
-    KV blocks fetched on every node."""
+    :meth:`lookup_grouped`'s ``race.to_device`` (the grouped queries and
+    each key's slot in the padded answers, packed into one array by
+    :func:`~repro.kernels.race_lookup.ops.pack_queries` and put once,
+    sharded on the mesh: one device buffer a device, ``h2d_buffers``)
+    and ``race.kernel`` (``variant="pool_mesh"``: dispatch of one SPMD
+    program, :func:`~repro.kernels.race_lookup.ops.pool_mesh_lookup`,
+    that unpacks the queries on each device, runs the pool kernel per
+    node and gathers the answers into the keys' order across the
+    devices). Nothing is replicated from the host. The answers come back
+    as device arrays; ``stats.blocks`` sums on the devices the KV blocks
+    fetched on every node."""
+
+    counts_buffers = True
 
     def __init__(self, n_nodes: int = 4, n_buckets: int = 1024,
                  nslot: int = 8, vdim: int = 128, capacity: int = 4096,
@@ -1023,25 +1046,29 @@ class MeshPoolRaceTable(_Resident):
                        impl: str = "pallas"):
         """The device half of ``lookup_batch``, on queries already grouped
         per node: qkeys, qfps (N, QCAP) i32, bucket_idx (N, QCAP, 2) i32,
-        inv (NQ,) i32 (:func:`~repro.kernels.race_lookup.ops.
-        pool_mesh_lookup`'s operands). Puts them on the mesh, ships the
-        tables first if they are stale, and dispatches the program.
-        Returns device arrays (values (NQ, VDIM), found (NQ,) i32, blocks
-        fetched, an i32 scalar)."""
+        inv (NQ,) i32, each key's slot ``node * QCAP + within``. Ships the
+        tables first if they are stale, packs the queries into one array
+        (:func:`~repro.kernels.race_lookup.ops.pack_queries`), puts it
+        once, sharded on the mesh, and dispatches the program
+        (:func:`~repro.kernels.race_lookup.ops.pool_mesh_lookup`), which
+        unpacks it on the devices. Returns device arrays (values (NQ,
+        VDIM), found (NQ,) i32, blocks fetched, an i32 scalar)."""
         import jax
 
         from repro import obs
-        from repro.kernels.race_lookup.ops import pool_mesh_lookup
+        from repro.kernels.race_lookup.ops import (pack_queries,
+                                                   pool_mesh_lookup)
         tables = self._ship_if_stale()
-        grouped = (qkeys, qfps, bucket_idx)
-        with obs.span("race.to_device", h2d_bytes=sum(
-                a.nbytes for a in grouped) + inv.nbytes * self.mesh.size):
-            grouped, inv = jax.block_until_ready((
-                jax.device_put(grouped, self._by_node),
-                jax.device_put(inv, self._replicated)))
+        with obs.span("race.to_device") as add:
+            queries = pack_queries(qkeys, qfps, bucket_idx, inv)
+            add(h2d_bytes=queries.nbytes)
+            queries = jax.block_until_ready(
+                jax.device_put(queries, self._by_node))
+            add(h2d_buffers=len(queries.sharding.device_set))
         with obs.span("race.kernel",
                       variant="pool_mesh" if impl == "pallas" else impl):
-            return pool_mesh_lookup(*tables, *grouped, inv, mesh=self.mesh,
+            return pool_mesh_lookup(*tables, queries, nq=len(inv),
+                                    qcap=qfps.shape[1], mesh=self.mesh,
                                     nslot=self.nslot, impl=impl)
 
     def lookup_batch(self, keys: np.ndarray, impl: str = "pallas"):

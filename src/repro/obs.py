@@ -27,8 +27,8 @@ is their parent.
 The device RACE tables (``repro.kvs.race``) open one request per
 ``lookup_batch`` and count into their ``stats`` (``LookupStats``:
 ``calls``, ``keys``, ``h2d_bytes``, ``table_ships``, ``slots``,
-``padded_slots``, and ``blocks``, which the pool tables sum on the device
-with no span). Their spans:
+``padded_slots``, ``h2d_buffers`` (pool tables), and ``blocks``, which
+the pool tables sum on the device with no span). Their spans:
 
 =================  ====================================================
 ``race.prep``      host hashing (and shard or node routing); ``keys``
@@ -39,7 +39,11 @@ with no span). Their spans:
 ``race.to_device`` host-to-device copies, until they are on the device;
                    ``h2d_bytes``. The whole table ships in a span of
                    its own, with ``table_ships``, only on a lookup that
-                   follows an insert (or the first)
+                   follows an insert (or the first). The pool tables
+                   pack a lookup's queries into one array, put once
+                   (sharded by node over a mesh), and count the device
+                   buffers each put creates in ``h2d_buffers``: one a
+                   device for the queries, one a host array for a ship
 ``race.kernel``    dispatch of the jitted lookup; ``variant`` is
                    ``scalar``, ``sharded``, ``pool``, ``pool_mesh`` (or
                    ``ref``). The sharded one also dispatches the gather

@@ -201,11 +201,16 @@ def test_pool_table_spans_and_stats(tmp_path):
         ["race.prep", "race.to_device", "race.kernel"]]
     assert calls[0][3][1]["variant"] == calls[1][2][1]["variant"] == "pool"
     table_bytes = sum(a.nbytes for a in table.tables())
-    queries = len(keys) * (4 + 4 + 8)
+    # the query operands go as one packed array: keys, fingerprints and
+    # both buckets, 100 queries in a row of 128 lanes
+    queries = 4 * 128 * 4
     assert calls[0][1][1]["h2d_bytes"] == table_bytes
     assert calls[0][1][1]["table_ships"] == 1
+    assert calls[0][1][1]["h2d_buffers"] == 3     # index, keys, pool
     assert calls[0][2][1]["h2d_bytes"] == calls[1][1][1]["h2d_bytes"] \
         == queries
+    assert calls[0][2][1]["h2d_buffers"] == calls[1][1][1]["h2d_buffers"] \
+        == 1
     assert "table_ships" not in calls[1][1][1]
     # the blocks counted on the device: each lookup's fingerprint matches,
     # as the reference counts them from the same tables
@@ -214,4 +219,4 @@ def test_pool_table_spans_and_stats(tmp_path):
     assert int(table.stats.blocks) == 2 * int(np.sum(per_key)) >= 2 * 50
     assert dataclasses.replace(table.stats, blocks=0) == LookupStats(
         calls=2, keys=2 * len(keys), h2d_bytes=table_bytes + 2 * queries,
-        table_ships=1)
+        h2d_buffers=3 + 2, table_ships=1)

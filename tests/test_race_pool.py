@@ -6,9 +6,13 @@ bucket and fingerprint, and for the bulk insert's placement (the
 contract all three device tables keep is ``test_device_tables.py``).
 CPU, tiny tables (Pallas in interpret mode)."""
 
+import contextlib
+
 import numpy as np
 import pytest
 
+from repro import obs
+from repro.kernels.race_lookup import ops
 from repro.kernels.race_lookup.ref import pool_lookup_ref
 from repro.kvs.race import PoolRaceTable, fp8, prep_keys
 
@@ -193,3 +197,77 @@ def test_the_reference_reads_the_tables_as_shipped():
     np.testing.assert_array_equal(np.asarray(f), found.astype(np.int32))
     np.testing.assert_array_equal(_bits(np.asarray(v)), _bits(want))
     assert (np.asarray(blocks)[:-1] >= 1).all()
+
+
+@pytest.fixture
+def puts(monkeypatch):
+    """The arguments of every query operands' ``race.to_device`` span."""
+    seen, real = [], obs.span
+
+    @contextlib.contextmanager
+    def span(name, **args):
+        with real(name, **args) as add:
+            def more(**known):
+                args.update(known)
+                add(**known)
+            yield more
+        if name == "race.to_device" and "table_ships" not in args:
+            seen.append(args)
+
+    monkeypatch.setattr(obs, "span", span)
+    return seen
+
+
+@pytest.mark.parametrize("impl", ["pallas", "ref"])
+@pytest.mark.parametrize("nq", [1, 3, 127, 128, 129, 300])
+def test_one_packed_put_a_lookup(puts, nq, impl):
+    """The query operands cross to the device as one packed array, one
+    device buffer, whatever the batch's length against the 128 lanes;
+    ``h2d_bytes`` counts that array, and the answers are the records."""
+    keys, vals = _records(400, seed=9)
+    table = PoolRaceTable(n_buckets=97, nslot=8, vdim=VDIM, capacity=400)
+    table.insert_many(keys, vals)
+    table.lookup_batch(keys[:1], impl=impl)          # ships the tables
+    before = (table.stats.h2d_bytes, table.stats.h2d_buffers, len(puts))
+    asked = np.concatenate([keys[::2], np.arange(1, 200)])[:nq]
+    v, f = _lookup(table, asked, impl)
+    queries = ops.pack_queries(*table.prep(asked))
+    assert queries.shape == (4, -(-nq // 128) * 128)
+    assert [(a["h2d_buffers"], a["h2d_bytes"]) for a in puts[before[2]:]] \
+        == [(1, queries.nbytes)]
+    assert table.stats.h2d_bytes - before[0] == queries.nbytes
+    assert table.stats.h2d_buffers - before[1] == 1
+    want, found = _expect(keys, vals, asked)
+    np.testing.assert_array_equal(f, found.astype(np.int32))
+    np.testing.assert_array_equal(_bits(v), _bits(want))
+
+
+def test_the_packed_rows_are_the_operands():
+    """Pool rows: keys, fingerprints, first and second bucket, in the
+    query's lane; the mesh's fifth row is each node's chunk of ``inv``,
+    zero-padded, and the lanes cover the wider of QCAP and the chunk."""
+    qkeys = np.array([5, 6, 7], np.int32)
+    fps = np.array([9, 10, 11], np.int32)
+    bidx = np.array([[1, 2], [3, 4], [5, 6]], np.int32)
+    got = ops.pack_queries(qkeys, fps, bidx)
+    assert got.dtype == np.int32 and got.shape == (4, 128)
+    np.testing.assert_array_equal(got[:, :3], [[5, 6, 7], [9, 10, 11],
+                                               [1, 3, 5], [2, 4, 6]])
+    assert not got[:, 3:].any()
+    # two nodes of QCAP 8, seven keys: chunks of four
+    inv = np.arange(7, dtype=np.int32) + 1
+    grouped = ops.pack_queries(np.ones((2, 8), np.int32),
+                               np.ones((2, 8), np.int32),
+                               np.ones((2, 8, 2), np.int32), inv)
+    assert grouped.shape == (2, 5, 128)
+    np.testing.assert_array_equal(grouped[:, 4, :4], [[1, 2, 3, 4],
+                                                      [5, 6, 7, 0]])
+    assert not grouped[:, 4, 4:].any() and grouped[:, :4, :8].all()
+    assert not grouped[:, :4, 8:].any()
+    wide = ops.pack_queries(np.ones((2, 8), np.int32),
+                            np.ones((2, 8), np.int32),
+                            np.ones((2, 8, 2), np.int32),
+                            np.arange(300, dtype=np.int32))
+    assert wide.shape == (2, 5, 256)
+    np.testing.assert_array_equal(wide[:, 4, :150].reshape(-1),
+                                  np.arange(300))

@@ -17,17 +17,21 @@ import pytest
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 CASES = ["zipf_duplicates", "missing", "empty", "one_node_idle",
-         "one_node_only"]
+         "one_node_only", "ragged", "fewer_than_nodes"]
 LAYOUTS = [4, 2, 1]            # devices for the four nodes
 IMPLS = ["pallas", "ref"]
 
 CODE = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-import importlib.util, json, sys
-sys.path.insert(0, os.path.join(%(root)r, "src"))
+import contextlib, importlib.util, json, sys
+sys.path[:0] = [os.path.join(%(root)r, "src"), %(root)r]
 import numpy as np
 import jax
+from repro import obs
+from repro.kernels.race_lookup import ops
+from repro.kernels.race_lookup.pool import QBLOCK
+from repro.kernels.race_lookup.race_lookup import _group_slots
 from repro.kvs.race import MeshPoolRaceTable, PoolRaceTable, shard_of_keys
 
 spec = importlib.util.spec_from_file_location(
@@ -52,8 +56,51 @@ batches = {
     "one_node_idle": keys[node != 2][:90],
     "one_node_only": np.concatenate([keys[node == 1][:70],
                                      absent[shard_of_keys(absent, N) == 1]]),
+    "ragged": np.concatenate([keys[-250:], absent[:51]]),
+    "fewer_than_nodes": np.array([keys[5], absent[3], keys[9]]),
 }
 out = {"answers": {}, "stats": {}}
+puts = []            # each query operands' race.to_device: its arguments
+active = []          # and the device_puts made inside it, while it is open
+real_span, real_put = obs.span, jax.device_put
+
+
+@contextlib.contextmanager
+def span(name, **args):
+    if name != "race.to_device" or "table_ships" in args:
+        with real_span(name, **args) as add:
+            yield add
+        return
+    seen = {"device_puts": []}
+    active.append(seen)
+    with real_span(name, **args) as add:
+        def more(**known):
+            seen.update(known)
+            add(**known)
+        yield more
+    active.pop()
+    puts.append(seen)
+
+
+def device_put(x, *a, **k):
+    out = real_put(x, *a, **k)
+    if active:
+        active[-1]["device_puts"].append(
+            [list(np.shape(x)), str(out.sharding.spec)])
+    return out
+
+
+obs.span = span
+jax.device_put = device_put
+
+
+def packed(table, asked):
+    # the packed queries of asked, as lookup_batch groups them
+    qkeys, qfps, bidx, node = table.prep(asked)
+    qfps, bidx, inv, _ = _group_slots(qfps, bidx, node, N, QBLOCK)
+    grouped = np.zeros(qfps.size, np.int32)
+    grouped[inv] = qkeys
+    return ops.pack_queries(grouped.reshape(qfps.shape), qfps, bidx, inv)
 
 
 def bits(a):
@@ -76,8 +123,12 @@ for d in %(layouts)r:
     for case, asked in batches.items():
         for impl in %(impls)r:
             before = (table.stats.slots, table.stats.padded_slots,
-                      int(table.stats.blocks))
+                      int(table.stats.blocks), table.stats.h2d_bytes,
+                      table.stats.h2d_buffers, table.stats.table_ships,
+                      len(puts))
             v, f = table.lookup_batch(asked, impl=impl)
+            ships = table.stats.table_ships - before[5]
+            mine = puts[before[6]:]
             v, f = np.asarray(v), np.asarray(f)
             want_v, want_f = reference.get_many(asked)
             bad = (f != 0) != want_f
@@ -91,6 +142,16 @@ for d in %(layouts)r:
                 "slots": table.stats.slots - before[0],
                 "padded": table.stats.padded_slots - before[1],
                 "blocks": int(table.stats.blocks) - before[2],
+                "puts": [[a["h2d_buffers"], a["h2d_bytes"]] for a in mine],
+                "device_puts": [a["device_puts"] for a in mine],
+                "packed_shape": list(packed(table, asked).shape)
+                if len(asked) else [],
+                "packed_bytes": packed(table, asked).nbytes if len(asked)
+                else 0,
+                "h2d_buffers": table.stats.h2d_buffers - before[4]
+                - ships * 3 * N,
+                "h2d_bytes": table.stats.h2d_bytes - before[3]
+                - ships * sum(a.nbytes for n in table.tables() for a in n),
                 "device": isinstance(
                     table.lookup_batch(asked[:1], impl=impl)[0], jax.Array)}
     out["stats"][str(d)] = {"calls": table.stats.calls,
@@ -99,6 +160,19 @@ for d in %(layouts)r:
     table.insert(2 ** 30 + 500, np.ones(VDIM, np.float32))
     table.lookup_batch(np.array([2 ** 30 + 500]))
     out["stats"][str(d)]["ships_after_insert"] = table.stats.table_ships
+# the benchmark's warm-up compiles every program a window of the same
+# batch length runs: a lookup of a sampled batch adds no compiled program
+from bench.systems import race_pool_mesh
+table = MeshPoolRaceTable(n_nodes=N, n_buckets=NB, nslot=8, vdim=VDIM,
+                          capacity=capacity, devices=jax.devices())
+table.insert_many(keys, vals)
+sample = [keys[rng.integers(0, RECORDS, 64)] for _ in range(8)]
+shapes = race_pool_mesh.warm_up(table, sample)
+cached = ops.pool_mesh_lookup._cache_size()
+for asked in sample:
+    table.lookup_batch(asked)
+out["warm"] = {"shapes": shapes,
+               "added": ops.pool_mesh_lookup._cache_size() - cached}
 out["batch_nodes"] = {case: np.bincount(shard_of_keys(b, N),
                                         minlength=N).tolist()
                       for case, b in batches.items()}
@@ -139,7 +213,7 @@ def test_answers_are_the_references(result, case, impl, devices):
     got = result["answers"][f"{case}-{impl}-{devices}"]
     assert got["mismatched"] == 0
     n = {"zipf_duplicates": 300, "missing": 100, "empty": 0,
-         "one_node_idle": 90}.get(case)
+         "one_node_idle": 90, "ragged": 301, "fewer_than_nodes": 3}.get(case)
     if n is not None:
         assert got["shape"] == [[n, 32], [n]]
     assert got["dtype"] == ["float32", "int32"]
@@ -148,6 +222,37 @@ def test_answers_are_the_references(result, case, impl, devices):
         assert got["found"] == 40
     if case == "zipf_duplicates":
         assert got["found"] == 300
+    if case == "ragged":
+        assert got["found"] == 250
+    if case == "fewer_than_nodes":
+        assert got["found"] == 2
+
+
+@pytest.mark.parametrize("devices", LAYOUTS)
+@pytest.mark.parametrize("case", CASES)
+def test_one_packed_put_a_lookup_one_buffer_a_device(result, case, devices):
+    """The queries cross to the devices in one ``race.to_device``: one
+    array, sharded by node, so one device buffer per device and nothing
+    replicated; its bytes are the packed array's, and the table's
+    counters agree with the span's arguments. An empty batch puts
+    nothing."""
+    for impl in IMPLS:
+        got = result["answers"][f"{case}-{impl}-{devices}"]
+        if case == "empty":
+            assert got["puts"] == [] and got["packed_bytes"] == 0
+        else:
+            assert got["puts"] == [[devices, got["packed_bytes"]]]
+            assert got["device_puts"] == [[[got["packed_shape"],
+                                            "PartitionSpec('node',)"]]]
+            n, rows, lanes = got["packed_shape"]
+            assert (n, rows) == (4, 5) and lanes % 128 == 0
+        assert got["h2d_buffers"] == len(got["puts"]) * devices
+        assert got["h2d_bytes"] == got["packed_bytes"]
+
+
+def test_the_warm_up_compiles_what_a_lookup_runs(result):
+    warm = result["warm"]
+    assert warm["shapes"] and warm["added"] == 0
 
 
 @pytest.mark.parametrize("devices", LAYOUTS)

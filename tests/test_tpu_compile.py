@@ -22,7 +22,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.race_lookup import pool as pool_kernel
-from repro.kernels.race_lookup.ops import NODE_AXIS, pool_mesh_lookup
+from repro.kernels.race_lookup.ops import (NODE_AXIS, pack_queries,
+                                           pool_lookup, pool_mesh_lookup)
 from repro.kernels.race_lookup.pool import LANES, pool_lookup_pallas
 from repro.kernels.race_lookup.race_lookup import (in_key_order,
                                                    race_lookup_pallas,
@@ -120,6 +121,28 @@ def test_the_pool_kernel_leaves_index_and_pool_where_they_are(
                            for p in ("index", "keys", "pool")), line
 
 
+def test_the_pool_table_unpacks_its_packed_queries_on_the_chip(
+        shape_on_chip, monkeypatch):
+    """``PoolRaceTable``'s own path at one node's 8M records: the jitted
+    ``pool_lookup`` takes the 4096 queries as one packed (4, 4096) array,
+    unpacks it on the chip and runs the kernel (``%pool_lookup``), with
+    the resident index, keys and pool reaching it as they are."""
+    monkeypatch.setattr(pool_kernel, "interpret_mode", lambda: False)
+    s = shape_on_chip
+    text = pool_lookup.lower(
+        s((-(-POOL_BUCKETS * 2 * NSLOT // LANES), 1, LANES)),
+        s((-(-POOL_RECORDS // LANES), 1, LANES)),
+        s((POOL_RECORDS, 1, VDIM), jnp.float32), s((4, NQ)), nq=NQ,
+        nslot=NSLOT).compile().as_text()
+    assert any(line.lstrip().startswith("%pool_lookup")
+               and 'custom_call_target="tpu_custom_call"' in line
+               for line in text.splitlines())
+    for line in text.splitlines():
+        if " copy(" in line or "copy-start(" in line:
+            assert not any(f"%{p}" in line.split("copy", 1)[1]
+                           for p in ("index", "keys", "pool")), line
+
+
 def test_the_sharded_answers_are_ordered_by_a_plain_gather(shape_on_chip):
     """The padded answers of a Zipfian multi-get (QCAP 256) go into the
     keys' order on the chip by an XLA gather: no Pallas kernel, and a
@@ -141,9 +164,10 @@ def test_the_pool_over_four_nodes_compiles_one_node_a_chip(topo,
     v5e: each chip holds one node's 8M-record index and pool and runs the
     pool kernel (named ``pool_lookup`` in the HLO, which the trace
     metrics match) on its node's queries, at the busiest node's padded
-    size for a Zipfian 4096-key multi-get; a collective brings the
-    answers to every chip. One node's tables and the program's own
-    buffers fit a chip's 16 GB, with no copy of a pool."""
+    size for a Zipfian 4096-key multi-get, unpacked on the chip from its
+    block of the one packed query array; a collective brings the answers
+    to every chip. One node's tables and the program's own buffers fit a
+    chip's 16 GB, with no copy of a pool."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     import numpy as np
 
@@ -152,18 +176,23 @@ def test_the_pool_over_four_nodes_compiles_one_node_a_chip(topo,
     n, nb, qcap = MESH["nodes"], MESH["buckets"], 1280
     cap = -(-MESH["recordcount"] // n) + 4096      # the busiest node's
     mesh = Mesh(np.array(topo.devices[:n]), (NODE_AXIS,))
-    by_node, replicated = (NamedSharding(mesh, P(NODE_AXIS)),
-                           NamedSharding(mesh, P()))
+    by_node = NamedSharding(mesh, P(NODE_AXIS))
+    # the queries as ``MeshPoolRaceTable`` puts them: one packed array,
+    # sharded by node, nothing replicated
+    packed = pack_queries(np.zeros((n, qcap), np.int32),
+                          np.zeros((n, qcap), np.int32),
+                          np.zeros((n, qcap, 2), np.int32),
+                          np.zeros(NQ, np.int32))
+    assert packed.shape == (n, 5, qcap)
 
-    def s(shape, dtype=jnp.int32, sharding=by_node):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    def s(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=by_node)
 
     compiled = pool_mesh_lookup.lower(
         s((n, -(-nb * 2 * NSLOT // LANES), 1, LANES)),
         s((n, -(-cap // LANES), 1, LANES)),
         s((n, cap, 1, MESH["vdim"]), jnp.float32),
-        s((n, qcap)), s((n, qcap)), s((n, qcap, 2)),
-        s((NQ,), sharding=replicated), mesh=mesh,
+        s(packed.shape), nq=NQ, qcap=qcap, mesh=mesh,
         nslot=MESH["slots_per_bucket"]).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
